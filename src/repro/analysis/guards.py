@@ -64,8 +64,7 @@ def _flat_cache_sizes(target: Any) -> Dict[str, int]:
 @contextlib.contextmanager
 def no_recompile(target: Any, *, bound: int = 0) -> Iterator[None]:
     """Assert ``target`` compiles at most ``bound`` NEW jit entries inside
-    the block.  Stages whose cache size is unreadable (-1, older jaxlib)
-    are skipped rather than guessed."""
+    the block."""
     before = _flat_cache_sizes(target)
     yield
     after = _flat_cache_sizes(target)
@@ -73,9 +72,7 @@ def no_recompile(target: Any, *, bound: int = 0) -> Iterator[None]:
     total = 0
     for key, n_after in after.items():
         n_before = before.get(key, 0)
-        if n_after < 0 or n_before < 0:
-            continue                       # cache size probe unsupported
-        delta = n_after - max(0, n_before)
+        delta = n_after - n_before
         if delta > 0:
             grown[key] = (n_before, n_after)
             total += delta

@@ -8,8 +8,9 @@ device execution — and the resulting jaxprs are walked for hazards the
 serving invariants assume away:
 
 * **JXP001** — a callback primitive (``debug_callback`` /
-  ``pure_callback`` / ``io_callback``) compiled into a stage: a host
-  round-trip per dispatch that the transfer guard cannot see.
+  ``debug_print`` / ``pure_callback`` / ``io_callback``) compiled into a
+  stage: a host round-trip per dispatch that the transfer guard cannot
+  see.
 * **JXP002** — a ``device_put`` primitive inside a stage: a host upload
   smuggled into the traced graph instead of going through the scheduler's
   cached explicit-upload paths (``_chunk_t0`` / ``_thr_device``).
@@ -48,8 +49,8 @@ from repro.analysis.rules import RULES
 LARGE_CONST_ELEMS = 16384
 
 # primitives that call back into the host per dispatch
-_CALLBACK_PRIMS = ("debug_callback", "pure_callback", "io_callback",
-                   "callback")
+_CALLBACK_PRIMS = ("debug_callback", "debug_print", "pure_callback",
+                   "io_callback", "callback")
 
 
 def _finding(rule: str, path: str, message: str, snippet: str) -> Finding:

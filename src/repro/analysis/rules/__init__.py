@@ -147,9 +147,9 @@ _ALL = [
          example="SchedulerConfig(paged=True, page_size=12)",
          fix="pick a positive multiple of 8 (the repo default is 16)"),
     Rule("JXP001", "jaxpr-host-callback", "error",
-         "a callback primitive (debug_callback/pure_callback/io_callback) "
-         "compiled into a registered serving stage: every dispatch pays a "
-         "host round-trip the transfer guard cannot see",
+         "a callback primitive (debug_callback/debug_print/pure_callback/"
+         "io_callback) compiled into a registered serving stage: every "
+         "dispatch pays a host round-trip the transfer guard cannot see",
          example="def step(x):\n    jax.debug.print(\"x={x}\", x=x)\n"
                  "    return x + 1\n# registered as a jitted serving stage",
          fix="strip debug prints from serving stages before registering "

@@ -22,7 +22,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.cost_model import (CostGraph, DeviceProfile, LinkProfile,
                                    compute_time)
@@ -205,9 +204,9 @@ def staged_forward(model, params, batch, stage_of_block: Sequence[int],
                 jax.tree.map(lambda _: P(), params),
                 (P(dax, None, None) if enc_out is not None else P()),
                 )
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=in_specs,
-                   out_specs=P(dax, None, None),
-                   check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=in_specs,
+                       out_specs=P(dax, None, None),
+                       check_vma=False)
     return fn(x0, positions, params, enc_out if enc_out is not None
               else jnp.zeros((), x0.dtype))

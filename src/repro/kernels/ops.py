@@ -17,7 +17,12 @@ from repro.kernels import paged_attention as _pattn
 from repro.kernels.backend import resolve_interpret as _resolve_interpret
 
 
-def exit_head_entropy(x, w, *, block_t: int = 128, block_v: int = 512,
+# the exit-head kernel holds an f32 copy of each [D, block_v] weight tile
+# in VMEM, whose default scoped limit on v5e is 16 MiB
+_EXIT_W_TILE_BYTES = 12 * 2**20
+
+
+def exit_head_entropy(x, w, *, block_t: int = 128,
                       interpret: bool | None = None,
                       align_128: bool | None = None):
     """x [..., D], w [D, V] -> entropy [...] fp32 (pads T and V).
@@ -26,7 +31,9 @@ def exit_head_entropy(x, w, *, block_t: int = 128, block_v: int = 512,
     ``align_128`` (default: on for the compiled TPU path) forces MXU-legal
     tiling: T is padded to full ``block_t`` tiles and the inner dim to a
     multiple of 128 — zero feature columns/rows contribute nothing to the
-    logits, so the entropy is unchanged.
+    logits, so the entropy is unchanged.  The vocab tile is 512 wide, or
+    narrower where its f32 weight tile would overflow the VMEM budget
+    (d_model 7168 compiles only at 256).
     """
     interpret = _resolve_interpret(interpret)
     align = (not interpret) if align_128 is None else align_128
@@ -35,6 +42,9 @@ def exit_head_entropy(x, w, *, block_t: int = 128, block_v: int = 512,
     x2 = x.reshape(-1, d)
     t = x2.shape[0]
     v = w.shape[1]
+    block_v = 512
+    while block_v > 128 and d * block_v * 4 > _EXIT_W_TILE_BYTES:
+        block_v //= 2
     bt = block_t if align else min(block_t, max(8, t))
     pt = (-t) % bt
     pv = (-v) % block_v

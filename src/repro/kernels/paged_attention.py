@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -80,10 +82,12 @@ def _paged_gqa_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_gqa_attention(q, pool_k, pool_v, tbl, pos, *, scale: float,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q [B, Nkv, G, H], pools [Nkv, n_pages, P, H] (head-major, padded),
     tbl [B, pps] int32 (CLIPPED into [0, n_pages)), pos [B] int32 ->
-    o [B, Nkv, G, H] fp32."""
+    o [B, Nkv, G, H] fp32.  ``interpret=None`` auto-detects the backend
+    (interpret only off-TPU)."""
+    interpret = resolve_interpret(interpret)
     b, nkv, g, h = q.shape
     n_pages, page = pool_k.shape[1], pool_k.shape[2]
     pps = tbl.shape[1]
@@ -160,11 +164,13 @@ def _paged_mla_kernel(tbl_ref, pos_ref, q_ref, kc_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
 def paged_mla_attention(q_cat, pool_cat, tbl, pos, *, rank: int,
-                        scale: float, interpret: bool = True):
+                        scale: float, interpret: bool | None = None):
     """q_cat [B, N, R + Hr] (absorbed latent queries || rope queries),
     pool_cat [n_pages, P, R + Hr] (compressed-kv || rope-k pages),
     tbl [B, pps] int32 (clipped), pos [B] int32 -> latent o [B, N, R] fp32.
-    ``rank`` is the PADDED latent width R inside the concatenation."""
+    ``rank`` is the PADDED latent width R inside the concatenation.
+    ``interpret=None`` auto-detects the backend (interpret only off-TPU)."""
+    interpret = resolve_interpret(interpret)
     b, n, dcat = q_cat.shape
     page = pool_cat.shape[1]
     pps = tbl.shape[1]

@@ -2,8 +2,11 @@
 combination against placeholder devices; record memory/cost analysis and
 roofline terms.
 
-MUST be run as a script / fresh process (the XLA_FLAGS lines below execute
-before any jax import, giving 512 host devices).  Results land in
+MUST be run as a script / fresh process (the environment lines below
+execute before any jax import, giving 512 host CPU devices).  The CPU
+platform is pinned so that on a machine with an accelerator the dry run
+neither takes the chip from another process nor builds its production
+meshes over the real devices.  Results land in
 experiments/dryrun/<arch>__<shape>__<mesh>.json.
 
 Usage:
@@ -11,6 +14,7 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --all            # everything
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_EXTRA_XLA", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
@@ -166,10 +170,7 @@ def dryrun_one(arch: str, shape_name: str, mesh_name: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    cost = compiled.cost_analysis() or {}
-    # older JAX returns one dict; newer returns a list of per-program dicts
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis()
     try:
         mem = compiled.memory_analysis()
         mem_d = {

@@ -1,8 +1,12 @@
 """Profile one (arch, shape, mesh): roofline terms + top byte/collective ops.
 
     PYTHONPATH=src python -m repro.launch.profile_pair granite-3-2b train_4k single
+
+Compiles on 512 placeholder CPU devices, like ``launch/dryrun.py``: the CPU
+platform is pinned before jax is imported, so the chip stays free.
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import sys

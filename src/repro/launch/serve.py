@@ -99,6 +99,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import Scenario
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Model, ShardCtx
 from repro.serving import (ClusterConfig, ContinuousBatchScheduler,
                            ModelGroup, MultiModelScheduler, Request,
@@ -200,9 +201,10 @@ def serve_poisson(arch: str, *, rate: float = 4.0, n_requests: int = 32,
     """Open-loop Poisson-arrival serving through the continuous-batching
     scheduler.  Returns a stats dict (p50/p95 latency, WALL-CLOCK sustained
     tok/s, host/device time split, jit cache sizes — the no-recompile
-    invariant).  ``async_decode`` runs the overlapped pipeline: on-device
-    sampling ring, double-buffered window dispatch, one batched readback
-    per ``readback_interval`` decode steps."""
+    invariant — and every request's generated tokens, in trace order).
+    ``async_decode`` runs the overlapped pipeline: on-device sampling ring,
+    double-buffered window dispatch, one batched readback per
+    ``readback_interval`` decode steps."""
     cfg = get_config(arch)
     model = Model(cfg, ShardCtx(None))
     if params is None:
@@ -256,6 +258,7 @@ def serve_poisson(arch: str, *, rate: float = 4.0, n_requests: int = 32,
         "peak_tokens_in_flight": sched.peak_tokens_in_flight,
         "jit_cache_sizes": sched.jit_cache_sizes(),
         "exit_stats": sched.exit_stats(),
+        "out_tokens": [list(r.out_tokens) for r in reqs],
     }
     if paged:
         stats["prefix_hit_tokens"] = sched.prefix_hit_tokens
@@ -517,6 +520,7 @@ def serve_tiered_poisson(arch: str, *, rate: float = 4.0,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="")
     ap.add_argument("--models", default="",

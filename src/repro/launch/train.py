@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.data import batch_for_model
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Model, ShardCtx
 from repro.training import (OptimizerConfig, TrainConfig, init_optimizer,
                             make_train_step, save_checkpoint,
@@ -76,6 +77,7 @@ def train(arch: str, steps: int, batch: int, seq: int, *, lr: float = 3e-4,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

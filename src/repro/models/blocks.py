@@ -157,10 +157,19 @@ _INIT = {
 }
 
 
+def _bf16_once_stacked(a):
+    """bf16 for leaves that become matrices when stacked (rank >= 1 per
+    layer) — the same leaves ``Model._cast`` casts on the stacked tree."""
+    return a.astype(jnp.bfloat16) if a.ndim >= 1 else a
+
+
 def init_scan_block(key, cfg, kind: str, n_units: int):
-    """Stacked params [n_units, ...] for a scanned block."""
+    """Stacked params [n_units, ...] for a scanned block.  Each layer is
+    cast to bf16 before stacking, so no f32 copy of the whole stack is ever
+    held: at published widths that copy is several GB of device memory."""
     keys = jax.random.split(key, n_units)
-    layers = [_INIT[kind](k, cfg) for k in keys]
+    layers = [jax.tree.map(_bf16_once_stacked, _INIT[kind](k, cfg))
+              for k in keys]
     return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
 
 

@@ -23,7 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.common import activation, scaled_init
 
@@ -287,12 +286,12 @@ def moe_ffn(params, x, cfg, ctx: ShardCtx = SINGLE):
         aux = jax.lax.pmean(aux, dax) if dax else aux
         return y.reshape(bl, sl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=ctx.mesh,
         in_specs=((P(dax or None, None, None), P(None, None))
                   + tuple(P(max_, None, None) for _ in wkeys)),
         out_specs=(P(dax or None, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], *[params[k] for k in wkeys])
 
     if "shared" in params:

@@ -2129,31 +2129,24 @@ class ContinuousBatchScheduler:
         """Compile counts of the hot jitted functions — the no-recompilation
         invariant the tests assert (slot churn must never retrace; every
         entry stays <= 1, and the number of decode entries is bounded by the
-        number of depth segments + exit probes + finalize).
-        Returns -1 per entry when the installed JAX doesn't expose a
-        compile-cache probe (private API; signature may churn)."""
-        def size(fn):
-            try:
-                return fn._cache_size()
-            except AttributeError:      # pragma: no cover - future JAX
-                return -1
-        sizes = {"prefill": size(self._prefill_chunk),
-                 "export_rows": size(self._export_rows),
-                 "import_rows": size(self._import_rows)}
+        number of depth segments + exit probes + finalize)."""
+        sizes = {"prefill": self._prefill_chunk._cache_size(),
+                 "export_rows": self._export_rows._cache_size(),
+                 "import_rows": self._import_rows._cache_size()}
         if self.cfg.segmented:
             for seg in self._segments:
-                sizes[f"segment{seg.index}"] = size(
-                    self._segment_fns[seg.index])
+                sizes[f"segment{seg.index}"] = \
+                    self._segment_fns[seg.index]._cache_size()
             for ei in range(self._n_exits):
-                sizes[f"probe{ei}"] = size(self._probe_fns[ei])
-            sizes["finalize"] = size(self._finalize)
+                sizes[f"probe{ei}"] = self._probe_fns[ei]._cache_size()
+            sizes["finalize"] = self._finalize._cache_size()
         else:
-            sizes["decode"] = size(self._decode)
+            sizes["decode"] = self._decode._cache_size()
             if self.cfg.async_decode:
-                sizes["decode_window"] = size(self._decode_window)
+                sizes["decode_window"] = self._decode_window._cache_size()
         if self._spec_k:
-            sizes["propose"] = size(self._propose)
-            sizes["verify"] = size(self._verify)
+            sizes["propose"] = self._propose._cache_size()
+            sizes["verify"] = self._verify._cache_size()
         return sizes
 
     def audit_stages(self) -> Dict[str, "StageSpec"]:

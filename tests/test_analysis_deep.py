@@ -10,7 +10,6 @@ import json
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.analysis import (RULES, check_cost_graphs, lint_source,
@@ -168,12 +167,15 @@ def test_jxp001_callback_primitive():
                                                           jnp.float32)]),
                        "<jaxpr:seed/callback>")
     assert _rules(f) == ["JXP001"]
-    assert "debug_callback" in f[0].message
+    # jax.debug.print lowers to its own debug_print primitive
+    assert "'debug_print'" in f[0].message
 
 
 def test_jxp002_device_put_primitive():
     def stage(x):
-        return x + jax.device_put(np.float32(1.0))
+        # a device_put of a host constant folds to a literal; one of a
+        # traced value stays a primitive in the jaxpr
+        return x + jax.device_put(x * 2.0)
     f, _ = audit_stage(_spec(stage, [jax.ShapeDtypeStruct((4,),
                                                           jnp.float32)]),
                        "<jaxpr:seed/device_put>")

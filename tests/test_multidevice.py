@@ -73,7 +73,7 @@ def test_moe_expert_parallel_multi_shard_matches_reference():
         mesh = jax.make_mesh((2, 4), ("data", "model"))   # E=4 over 4 shards
         y_ref, aux_ref = f.moe_ffn_reference(params, x, cfg,
                                              tokens_for_capacity=2 * 8)
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             y, aux = jax.jit(lambda p, x: f.moe_ffn(p, x, cfg,
                                                     f.ShardCtx(mesh)))(params, x)
         err = float(jnp.max(jnp.abs(jnp.asarray(y, jnp.float32)

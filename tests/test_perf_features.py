@@ -7,13 +7,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.models import Model
 from repro.models import ffn as ffn_mod
 from repro.models.attention import _sdpa, _sdpa_chunked, make_mask
-from repro.sharding.mesh_compat import make_abstract_mesh
 from repro.sharding.specs import ShardingRules
 
 
@@ -95,7 +94,7 @@ def test_quantize_preserves_dense_archs():
 # ---------------------------------------------------------------------------
 
 def test_dp_zero_replicates_weights_and_shards_moments():
-    mesh = make_abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("granite-3-2b")
     m = Model(cfg)
     shapes = jax.eval_shape(m.init, jax.random.PRNGKey(0))
@@ -116,7 +115,7 @@ def test_dp_zero_replicates_weights_and_shards_moments():
 
 
 def test_cache_specs_seq_shard_for_mla():
-    mesh = make_abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     rules = ShardingRules(mesh)
     cache = {
         "latent": jax.ShapeDtypeStruct((61, 128, 32768, 512), jnp.bfloat16),

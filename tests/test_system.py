@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ARCHS, INPUT_SHAPES, get_config, shape_applicable
 from repro.configs.base import InputShape
@@ -11,7 +11,6 @@ from repro.core import Scenario, build_cost_graph, plan_all
 from repro.data import batch_for_model
 from repro.models import Model, ShardCtx
 from repro.serving import ServeConfig, ServingEngine
-from repro.sharding.mesh_compat import make_abstract_mesh
 from repro.sharding.specs import ShardingRules
 from repro.training import (OptimizerConfig, TrainConfig, init_optimizer,
                             make_train_step)
@@ -75,7 +74,7 @@ def test_ssm_partition_boundary_is_cheap():
 def test_sharding_rules_cover_all_archs():
     """Every param leaf of every full config gets a valid spec on the
     production mesh (divisibility respected)."""
-    mesh = make_abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     for arch, cfg in ARCHS.items():
         m = Model(cfg)
         shapes = jax.eval_shape(m.init, jax.random.PRNGKey(0))
@@ -107,7 +106,7 @@ def test_shape_applicability_matrix():
 
 
 def test_zero_opt_spec_adds_data_axis():
-    mesh = make_abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     rules = ShardingRules(mesh)
     cfg = get_config("yi-6b")
     m = Model(cfg)
