@@ -823,9 +823,11 @@ class _PollSyncWalker:
     of the class's jitted stages (device values) and firing the SYN rules
     on implicit host syncs.  ``jax.device_get(...)`` launders the taint:
     the batched-readback idiom ``np.asarray(jax.device_get(x))`` and the
-    explicit ``int(jax.device_get(x))`` commit read are both legal."""
+    explicit ``int(jax.device_get(x))`` commit read are both legal.  So
+    does ``self._sync(what, x)``, the scheduler's one helper around
+    ``jax.device_get``."""
 
-    _DEVICE_GET = {"jax.device_get", "device_get"}
+    _DEVICE_GET = {"jax.device_get", "device_get", "self._sync"}
 
     def __init__(self, linter: ModuleLinter, fn: FuncNode,
                  jit_attrs: Set[str], dev_attrs: Set[str]):

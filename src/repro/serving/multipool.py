@@ -215,6 +215,7 @@ class MultiModelScheduler:
             rep.decode_dispatched += sub.decode_dispatched
             rep.host_ms += sub.host_ms
             rep.device_ms += sub.device_ms
+            rep.syncs += sub.syncs
             rep.tokens_in_flight += sub.tokens_in_flight
             active_depth += sub.decode_depth_frac * sub.n_active
             rep.completed += sub.completed

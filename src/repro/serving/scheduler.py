@@ -91,6 +91,16 @@ from repro.models import blocks as blocks_mod
 from repro.serving.paged import PageAllocator, RadixPrefixCache, chunk_digests
 
 
+def _span(name: str):
+    """Host span ``sched.<name>`` around scheduler phase ``name``.  It is
+    written into a running profiler trace, on the same clock as the
+    device's ops, so an idle gap on the chip can be named by the host
+    phase that left it; with no trace running it does nothing (about a
+    microsecond).  Never opened inside a jitted function or a per-slot
+    loop."""
+    return jax.profiler.TraceAnnotation("sched." + name)
+
+
 @dataclasses.dataclass
 class Request:
     """One serving request.  ``tokens`` is the prompt [S0] int; ``out_tokens``
@@ -106,8 +116,6 @@ class Request:
     model: str = ""
     # --- filled by the scheduler ---
     out_tokens: List[int] = dataclasses.field(default_factory=list)
-    t_submit: float = 0.0
-    t_admit: float = 0.0
     t_done: float = 0.0
     slot: int = -1
     done: bool = False
@@ -190,12 +198,15 @@ class StepReport:
     # external drivers must not treat it as idle.
     decode_steps: int = 0
     decode_dispatched: int = 0
-    # host/device wall-time split of this poll (satellite of the pipeline
-    # work: host_ms is python bookkeeping, device_ms is time blocked in
-    # jax.device_get readbacks) and tokens still in flight inside
+    # host/device wall-time split of this poll: ``syncs`` counts its
+    # blocking device->host reads (every one goes through ``_sync``: exit
+    # probes, token and ring readbacks, the first-token readback, counter
+    # flushes), device_ms is the time blocked in them and host_ms the rest
+    # of the poll.  tokens_in_flight: tokens still inside
     # dispatched-but-unread windows at poll end.
     host_ms: float = 0.0
     device_ms: float = 0.0
+    syncs: int = 0
     tokens_in_flight: int = 0
     completed: List[Request] = dataclasses.field(default_factory=list)
     # multi-model pools (repro.serving.multipool): the per-model sub-reports
@@ -418,7 +429,9 @@ class ContinuousBatchScheduler:
         self.host_ms_total = 0.0
         self.device_ms_total = 0.0
         self.peak_tokens_in_flight = 0
+        # running totals of _sync (never reset): a poll reports its deltas
         self._dev_s = 0.0
+        self._n_syncs = 0
 
         # --- jitted, fixed-shape device functions ---
         self._counters = jnp.zeros(self._n_exits + 1, jnp.int32)
@@ -890,7 +903,6 @@ class ContinuousBatchScheduler:
         req.tokens = toks.astype(np.int32)
         if req.req_id < 0:
             req.req_id = self.n_submitted
-        req.t_submit = time.time()
         self.n_submitted += 1
         self.queue.append(req)
 
@@ -930,24 +942,39 @@ class ContinuousBatchScheduler:
         double-buffered window pipeline (``_poll_async``): a poll either
         dispatches a decode window, commits one (a whole window's worth of
         ``decode_steps`` lands at once), or both — see docs/pipeline.md."""
-        if self.cfg.async_decode:
-            return self._poll_async(prefill_budget)
-        t_poll = time.perf_counter()
-        self._dev_s = 0.0
-        rep = self.prefill_poll(prefill_budget)
-        done_before = len(self.completed)
-        rep.decode_stepped = self.step()
-        rep.decode_steps = 1 if rep.decode_stepped else 0
-        rep.n_active = self._last_step_active
-        if rep.decode_stepped:
-            rep.decode_segments_run = self._last_segments_run
-            rep.decode_depth_frac = self._last_depth_frac
-        rep.completed += self.completed[done_before:]
-        rep.device_ms = self._dev_s * 1e3
-        rep.host_ms = (time.perf_counter() - t_poll) * 1e3 - rep.device_ms
+        with _span("poll"):
+            t_poll, dev_s, n_syncs = (time.perf_counter(), self._dev_s,
+                                      self._n_syncs)
+            if self.cfg.async_decode:
+                rep = self._poll_async(prefill_budget)
+            else:
+                rep = self.prefill_poll(prefill_budget)
+                done_before = len(self.completed)
+                rep.decode_stepped = self.step()
+                rep.decode_steps = 1 if rep.decode_stepped else 0
+                rep.n_active = self._last_step_active
+                if rep.decode_stepped:
+                    rep.decode_segments_run = self._last_segments_run
+                    rep.decode_depth_frac = self._last_depth_frac
+                rep.completed += self.completed[done_before:]
+            rep.syncs = self._n_syncs - n_syncs
+            rep.device_ms = (self._dev_s - dev_s) * 1e3
+            rep.host_ms = (time.perf_counter() - t_poll) * 1e3 \
+                - rep.device_ms
         self.host_ms_total += rep.host_ms
         self.device_ms_total += rep.device_ms
         return rep
+
+    def _sync(self, what: str, x):
+        """``jax.device_get(x)``: the scheduler's one way to block on the
+        chip.  The wait is a ``sched.sync.<what>`` span, counted in
+        ``StepReport.syncs`` and timed into ``device_ms``."""
+        t0 = time.perf_counter()
+        with _span("sync." + what):
+            out = jax.device_get(x)
+        self._dev_s += time.perf_counter() - t0
+        self._n_syncs += 1
+        return out
 
     def prefill_poll(self, prefill_budget: Optional[int] = None) -> StepReport:
         """Admission + chunked prefill only — no decode step.  Speculative
@@ -1029,63 +1056,64 @@ class ContinuousBatchScheduler:
         free = [i for i in range(self.cfg.n_slots) if self.slot_req[i] is None]
         if not free or not self.queue:
             return []
-        take: List[int] = []
-        reqs: List[Request] = []
-        starts: Dict[int, int] = {}
-        for slot in free:
-            if not self.queue:
-                break
-            r = self.queue[0]
-            if self.page_alloc is not None:
-                st = self._reserve_pages(slot, r)
-                if st is None:
-                    break              # pool full: defer, keep FIFO order
-                starts[slot] = st
-            self.queue.popleft()
-            take.append(slot)
-            reqs.append(r)
-        if not reqs:
-            return []
-        b, chunk = self.cfg.n_slots, self.cfg.prefill_chunk
-        max_len = max(r.tokens.size for r in reqs)
-        n_chunks = -(-max_len // chunk)
-        tokens = np.zeros((b, n_chunks * chunk), np.int32)
-        lengths = np.zeros(b, np.int32)
-        admit = np.zeros(b, bool)
-        start = np.zeros(b, np.int32)
-        now = time.time()
-        for slot, r in zip(take, reqs):
-            tokens[slot, : r.tokens.size] = r.tokens
-            lengths[slot] = r.tokens.size
-            admit[slot] = True
-            start[slot] = starts.get(slot, 0)
-            r.slot, r.t_admit = slot, now
-            self.slot_req[slot] = r
-
-        if self.page_alloc is not None:
-            fresh = None               # paged prefill writes the pool itself
-            if self._reset_states is not None:
-                self.cache = self._reset_states(self.cache,
-                                                jnp.asarray(admit))
-        else:
-            fresh = self._init_cache()
-        if self.model.cfg.family == "encdec":
-            ec = self.model.cfg.encdec
-            frames = np.zeros((b, ec.encoder_seq_len, self.model.cfg.d_model),
-                              np.float32)
+        with _span("admit"):
+            take: List[int] = []
+            reqs: List[Request] = []
+            starts: Dict[int, int] = {}
+            for slot in free:
+                if not self.queue:
+                    break
+                r = self.queue[0]
+                if self.page_alloc is not None:
+                    st = self._reserve_pages(slot, r)
+                    if st is None:
+                        break          # pool full: defer, keep FIFO order
+                    starts[slot] = st
+                self.queue.popleft()
+                take.append(slot)
+                reqs.append(r)
+            if not reqs:
+                return []
+            b, chunk = self.cfg.n_slots, self.cfg.prefill_chunk
+            max_len = max(r.tokens.size for r in reqs)
+            n_chunks = -(-max_len // chunk)
+            tokens = np.zeros((b, n_chunks * chunk), np.int32)
+            lengths = np.zeros(b, np.int32)
+            admit = np.zeros(b, bool)
+            start = np.zeros(b, np.int32)
             for slot, r in zip(take, reqs):
-                assert r.frames is not None, "encdec request needs frames"
-                frames[slot] = np.asarray(r.frames, np.float32)
-            fresh = self._prime(self.params, fresh,
-                                jnp.asarray(frames, jnp.bfloat16))
+                tokens[slot, : r.tokens.size] = r.tokens
+                lengths[slot] = r.tokens.size
+                admit[slot] = True
+                start[slot] = starts.get(slot, 0)
+                r.slot = slot
+                self.slot_req[slot] = r
 
-        self._pending = _PendingPrefill(
-            reqs=reqs, slots=take, tokens=tokens, lengths=lengths,
-            lengths_d=jnp.asarray(lengths), admit=admit, cache=fresh,
-            last=self._fresh_last(),
-            next_chunk=0, n_chunks=n_chunks,
-            start=start, start_d=jnp.asarray(start))
-        return reqs
+            if self.page_alloc is not None:
+                fresh = None           # paged prefill writes the pool itself
+                if self._reset_states is not None:
+                    self.cache = self._reset_states(self.cache,
+                                                    jnp.asarray(admit))
+            else:
+                fresh = self._init_cache()
+            if self.model.cfg.family == "encdec":
+                ec = self.model.cfg.encdec
+                frames = np.zeros(
+                    (b, ec.encoder_seq_len, self.model.cfg.d_model),
+                    np.float32)
+                for slot, r in zip(take, reqs):
+                    assert r.frames is not None, "encdec request needs frames"
+                    frames[slot] = np.asarray(r.frames, np.float32)
+                fresh = self._prime(self.params, fresh,
+                                    jnp.asarray(frames, jnp.bfloat16))
+
+            self._pending = _PendingPrefill(
+                reqs=reqs, slots=take, tokens=tokens, lengths=lengths,
+                lengths_d=jnp.asarray(lengths), admit=admit, cache=fresh,
+                last=self._fresh_last(),
+                next_chunk=0, n_chunks=n_chunks,
+                start=start, start_d=jnp.asarray(start))
+            return reqs
 
     def _make_reset_states(self):
         """Jitted row-reset for non-pool cache leaves: admitted slots' state
@@ -1164,22 +1192,23 @@ class ContinuousBatchScheduler:
                 self.prefill_chunks_skipped += 1
                 ci += 1
                 continue
-            if paged:
-                self.cache, p.last = self._prefill_chunk(
-                    self.params, self.cache,
-                    jnp.asarray(p.tokens[:, lo:hi]),
-                    self._chunk_t0(ci), p.lengths_d, p.start_d, p.last,
-                    self._tbl_dev())
-                rep.prefill_tokens += int(np.sum(np.clip(
-                    np.minimum(p.lengths, hi) - np.maximum(p.start, lo),
-                    0, None)))
-            else:
-                p.cache, p.last = self._prefill_chunk(
-                    self.params, p.cache,
-                    jnp.asarray(p.tokens[:, lo:hi]),
-                    self._chunk_t0(ci), p.lengths_d, p.last)
-                rep.prefill_tokens += int(
-                    np.sum(np.clip(p.lengths - lo, 0, hi - lo)))
+            with _span("prefill_chunk"):
+                if paged:
+                    self.cache, p.last = self._prefill_chunk(
+                        self.params, self.cache,
+                        jnp.asarray(p.tokens[:, lo:hi]),
+                        self._chunk_t0(ci), p.lengths_d, p.start_d, p.last,
+                        self._tbl_dev())
+                    rep.prefill_tokens += int(np.sum(np.clip(
+                        np.minimum(p.lengths, hi) - np.maximum(p.start, lo),
+                        0, None)))
+                else:
+                    p.cache, p.last = self._prefill_chunk(
+                        self.params, p.cache,
+                        jnp.asarray(p.tokens[:, lo:hi]),
+                        self._chunk_t0(ci), p.lengths_d, p.last)
+                    rep.prefill_tokens += int(
+                        np.sum(np.clip(p.lengths - lo, 0, hi - lo)))
             rep.prefill_chunks += 1
             budget -= 1
             ci += 1
@@ -1188,38 +1217,39 @@ class ContinuousBatchScheduler:
             return
         # last chunk replayed: merge rows into the pool and go live (paged
         # prefill already wrote the pool in place — nothing to merge)
-        if not paged:
-            self.cache = self._merge(jnp.asarray(p.admit), p.cache,
-                                     self.cache)
-        # publish the finished prompts' full pages into the prefix tree
-        # BEFORE activation (an eos on the first sampled token finishes the
-        # slot and releases its table references; trie retention must
-        # already be in place so shared pages survive)
-        if self.prefix_cache is not None:
+        with _span("go_live"):
+            if not paged:
+                self.cache = self._merge(jnp.asarray(p.admit), p.cache,
+                                         self.cache)
+            # publish the finished prompts' full pages into the prefix tree
+            # BEFORE activation (an eos on the first sampled token finishes the
+            # slot and releases its table references; trie retention must
+            # already be in place so shared pages survive)
+            if self.prefix_cache is not None:
+                for slot, r in zip(p.slots, p.reqs):
+                    n_full = r.tokens.size // self.page_alloc.page_size
+                    if n_full:
+                        self.prefix_cache.insert(
+                            self._slot_digests[slot][:n_full], r.tokens,
+                            [int(pg) for pg in self._tbl[slot, :n_full]])
+            logits_np = np.asarray(self._sync("first_token", p.last))
             for slot, r in zip(p.slots, p.reqs):
-                n_full = r.tokens.size // self.page_alloc.page_size
-                if n_full:
-                    self.prefix_cache.insert(
-                        self._slot_digests[slot][:n_full], r.tokens,
-                        [int(pg) for pg in self._tbl[slot, :n_full]])
-        logits_np = np.asarray(jax.device_get(p.last))
-        for slot, r in zip(p.slots, p.reqs):
-            tok0 = self._sample_first(logits_np[slot])
-            r.out_tokens.append(tok0)
-            self.positions[slot] = p.lengths[slot]
-            self.current_tok[slot] = tok0
-            self.steps_taken[slot] = 0
-            self.active[slot] = True
-            self.n_admitted += 1
-            if r.eos_id is not None and tok0 == r.eos_id:
-                self._finish(slot)
-        self._pending = None
-        rep.prefill_done = True
-        # async decode: host state diverged from the device carry (new live
-        # slots) — the next window must be a FRESH dispatch.  Device-side
-        # ordering already serializes this merge after any in-flight window
-        # (both chain through self.cache donation).
-        self._carry_valid = False
+                tok0 = self._sample_first(logits_np[slot])
+                r.out_tokens.append(tok0)
+                self.positions[slot] = p.lengths[slot]
+                self.current_tok[slot] = tok0
+                self.steps_taken[slot] = 0
+                self.active[slot] = True
+                self.n_admitted += 1
+                if r.eos_id is not None and tok0 == r.eos_id:
+                    self._finish(slot)
+            self._pending = None
+            rep.prefill_done = True
+            # async decode: host state diverged from the device carry (new live
+            # slots) — the next window must be a FRESH dispatch.  Device-side
+            # ordering already serializes this merge after any in-flight window
+            # (both chain through self.cache donation).
+            self._carry_valid = False
 
     def _sample_first(self, logits_row) -> int:
         # seed-engine semantics: sampling needs BOTH temperature>0 and an rng
@@ -1232,8 +1262,8 @@ class ContinuousBatchScheduler:
             self._rng,
             jnp.asarray(np.asarray(1_000_003 + self._admit_tick, np.uint32)))
         scaled = np.asarray(logits_row, np.float32) / self.cfg.temperature
-        return int(jax.device_get(
-            jax.random.categorical(key, jnp.asarray(scaled))))
+        return int(self._sync(
+            "sample", jax.random.categorical(key, jnp.asarray(scaled))))
 
     # ------------------------------------------------------------------
     # decode: one fixed-shape step over the whole pool
@@ -1276,7 +1306,7 @@ class ContinuousBatchScheduler:
             self.stage_calls[f"probe{seg.exit_index}"] += 1
             # the short-circuit is an INTENDED per-probe round-trip: make
             # the d2h sync explicit so guard_polling can vouch for the rest
-            if not bool(jax.device_get(jnp.any(alive & active_d))):
+            if not bool(self._sync("probe", jnp.any(alive & active_d))):
                 break
         greedy, sampled, self._counters = self._finalize(
             self.params, x, self._counters, first_exit, active_d, key,
@@ -1292,48 +1322,51 @@ class ContinuousBatchScheduler:
         self._last_step_active = int(self.active.sum())
         if not self.active.any():
             return False
-        thr = (self.controller.threshold if self.controller is not None
-               else self.cfg.exit_threshold)
-        key = self._rng if self._rng is not None else self._zero_key
-        tokens = jnp.asarray(self.current_tok[:, None])
-        positions = jnp.asarray(self.positions.astype(np.int32))
-        active_d = jnp.asarray(self.active)
-        if self.cfg.segmented:
-            greedy, sampled = self._step_segmented(
-                tokens, positions, active_d, thr, key)
-        else:
-            args = (self.params, self.cache, tokens, positions, active_d,
-                    self._counters, self._thr_device(thr), key,
-                    jax.device_put(np.asarray(self._rng_tick, np.int32)))
-            if self.page_alloc is not None:
-                args = args + (self._tbl_dev(),)
-            greedy, sampled, self.cache, self._counters = self._decode(*args)
-            self._last_segments_run = len(self._segments)
-            self._last_depth_frac = 1.0
-        t0 = time.perf_counter()
-        nxt = np.asarray(jax.device_get(
-            sampled if self._rng is not None else greedy))
-        self._dev_s += time.perf_counter() - t0
-        self._step_idx += 1
-        self._rng_tick += 1
-        n_active = int(self.active.sum())
-        self.tokens_served += n_active
-        self._tokens_since_adapt += n_active
-        self.depth_weighted_tokens += self._last_depth_frac * n_active
-        self._depth_since_adapt += self._last_depth_frac * n_active
-        for slot in np.nonzero(self.active)[0]:
-            r = self.slot_req[slot]
-            self.steps_taken[slot] += 1
-            self.positions[slot] += 1
-            if self.steps_taken[slot] >= r.max_new:
-                self._finish(slot)      # last emitted token just ran; the
-                continue                # trailing sample is discarded
-            tok = int(nxt[slot])
-            r.out_tokens.append(tok)
-            self.current_tok[slot] = tok
-            if r.eos_id is not None and tok == r.eos_id:
-                self._finish(slot)
-        self._maybe_flush()
+        with _span("step"):
+            thr = (self.controller.threshold if self.controller is not None
+                   else self.cfg.exit_threshold)
+            key = self._rng if self._rng is not None else self._zero_key
+            tokens = jnp.asarray(self.current_tok[:, None])
+            positions = jnp.asarray(self.positions.astype(np.int32))
+            active_d = jnp.asarray(self.active)
+            with _span("dispatch"):
+                if self.cfg.segmented:
+                    greedy, sampled = self._step_segmented(
+                        tokens, positions, active_d, thr, key)
+                else:
+                    args = (self.params, self.cache, tokens, positions,
+                            active_d, self._counters, self._thr_device(thr),
+                            key, jax.device_put(
+                                np.asarray(self._rng_tick, np.int32)))
+                    if self.page_alloc is not None:
+                        args = args + (self._tbl_dev(),)
+                    greedy, sampled, self.cache, self._counters = \
+                        self._decode(*args)
+                    self._last_segments_run = len(self._segments)
+                    self._last_depth_frac = 1.0
+            nxt = np.asarray(self._sync(
+                "tokens", sampled if self._rng is not None else greedy))
+            with _span("commit"):
+                self._step_idx += 1
+                self._rng_tick += 1
+                n_active = int(self.active.sum())
+                self.tokens_served += n_active
+                self._tokens_since_adapt += n_active
+                self.depth_weighted_tokens += self._last_depth_frac * n_active
+                self._depth_since_adapt += self._last_depth_frac * n_active
+                for slot in np.nonzero(self.active)[0]:
+                    r = self.slot_req[slot]
+                    self.steps_taken[slot] += 1
+                    self.positions[slot] += 1
+                    if self.steps_taken[slot] >= r.max_new:
+                        self._finish(slot)  # last emitted token just ran;
+                        continue            # the trailing sample is dropped
+                    tok = int(nxt[slot])
+                    r.out_tokens.append(tok)
+                    self.current_tok[slot] = tok
+                    if r.eos_id is not None and tok == r.eos_id:
+                        self._finish(slot)
+            self._maybe_flush()
         return True
 
     # ------------------------------------------------------------------
@@ -1346,8 +1379,6 @@ class ContinuousBatchScheduler:
         device computes N+1 while the host replays N's commits), else
         dispatch a fresh window from host state.  Exactly one batched d2h
         (the ring) per committed window; see docs/pipeline.md."""
-        t_poll = time.perf_counter()
-        dev_s = 0.0
         rep = self.prefill_poll(prefill_budget)
         # re-capture AFTER prefill_poll: it already stamped its completions
         done_before = len(self.completed)
@@ -1357,10 +1388,8 @@ class ContinuousBatchScheduler:
                 self._dispatch_window(from_carry=True)
                 rep.decode_dispatched += 1
             ring, part, _ = self._win_q.popleft()
-            t0 = time.perf_counter()
-            ring_np = np.asarray(jax.device_get(ring))
-            dev_s += time.perf_counter() - t0
-            self._commit_window(ring_np, part, rep)
+            self._commit_window(np.asarray(self._sync("ring", ring)), part,
+                                rep)
         elif self.active.any():
             self._dispatch_window(from_carry=False)
             rep.decode_dispatched += 1
@@ -1368,10 +1397,6 @@ class ContinuousBatchScheduler:
         rep.tokens_in_flight = self.tokens_in_flight
         self.peak_tokens_in_flight = max(self.peak_tokens_in_flight,
                                          rep.tokens_in_flight)
-        rep.device_ms = dev_s * 1e3
-        rep.host_ms = (time.perf_counter() - t_poll) * 1e3 - rep.device_ms
-        self.host_ms_total += rep.host_ms
-        self.device_ms_total += rep.device_ms
         return rep
 
     def _eos_host(self) -> np.ndarray:
@@ -1407,37 +1432,38 @@ class ContinuousBatchScheduler:
         A fresh dispatch uploads host state and opens a new chain whose
         participating-slot mask snapshots ``active`` (slots admitted later
         join at the NEXT fresh dispatch, never mid-chain)."""
-        thr = (self.controller.threshold if self.controller is not None
-               else self.cfg.exit_threshold)
-        key = self._rng if self._rng is not None else self._zero_key
-        if from_carry:
-            assert self._carry_valid and self._win_q
-            cur, pos, alive, budget = self._dev_carry
-            part = self._win_q[-1][1]          # same chain, same mask
-        else:
-            b = self.cfg.n_slots
-            budget_h = np.zeros(b, np.int32)
-            for slot in np.nonzero(self.active)[0]:
-                budget_h[slot] = (self.slot_req[slot].max_new
-                                  - self.steps_taken[slot])
-            cur = jnp.asarray(self.current_tok)
-            pos = jnp.asarray(self.positions.astype(np.int32))
-            alive = jnp.asarray(self.active)
-            budget = jnp.asarray(budget_h)
-            self._eos_dev = jnp.asarray(self._eos_host())
-            part = self.active.copy()
-        args = (self.params, self.cache, cur, pos, alive, budget,
-                self._eos_dev, self._counters, self._thr_device(thr), key,
-                jax.device_put(np.asarray(self._rng_tick, np.int32)),
-                self._flag_dev(self._rng is not None))
-        if self.page_alloc is not None:
-            args = args + (self._tbl_dev(),)
-        (self.cache, cur, pos, alive, budget,
-         self._counters, ring) = self._decode_window(*args)
-        self._dev_carry = (cur, pos, alive, budget)
-        self._carry_valid = True
-        self._rng_tick += self.cfg.readback_interval
-        self._win_q.append((ring, part, int((self.active & part).sum())))
+        with _span("dispatch"):
+            thr = (self.controller.threshold if self.controller is not None
+                   else self.cfg.exit_threshold)
+            key = self._rng if self._rng is not None else self._zero_key
+            if from_carry:
+                assert self._carry_valid and self._win_q
+                cur, pos, alive, budget = self._dev_carry
+                part = self._win_q[-1][1]          # same chain, same mask
+            else:
+                b = self.cfg.n_slots
+                budget_h = np.zeros(b, np.int32)
+                for slot in np.nonzero(self.active)[0]:
+                    budget_h[slot] = (self.slot_req[slot].max_new
+                                      - self.steps_taken[slot])
+                cur = jnp.asarray(self.current_tok)
+                pos = jnp.asarray(self.positions.astype(np.int32))
+                alive = jnp.asarray(self.active)
+                budget = jnp.asarray(budget_h)
+                self._eos_dev = jnp.asarray(self._eos_host())
+                part = self.active.copy()
+            args = (self.params, self.cache, cur, pos, alive, budget,
+                    self._eos_dev, self._counters, self._thr_device(thr), key,
+                    jax.device_put(np.asarray(self._rng_tick, np.int32)),
+                    self._flag_dev(self._rng is not None))
+            if self.page_alloc is not None:
+                args = args + (self._tbl_dev(),)
+            (self.cache, cur, pos, alive, budget,
+             self._counters, ring) = self._decode_window(*args)
+            self._dev_carry = (cur, pos, alive, budget)
+            self._carry_valid = True
+            self._rng_tick += self.cfg.readback_interval
+            self._win_q.append((ring, part, int((self.active & part).sum())))
 
     def _commit_window(self, ring: np.ndarray, part: np.ndarray,
                        rep: StepReport):
@@ -1452,48 +1478,49 @@ class ContinuousBatchScheduler:
         queued successor window permanently dead: drop it (and the carry)
         eagerly so a later admission reusing the slot indices can never
         replay the dead chain's garbage."""
-        R = self.cfg.readback_interval
-        replayed = 0
-        for j in range(R):
-            mask = self.active & part
-            if not mask.any():
-                break
-            n_active = int(mask.sum())
-            self.tokens_served += n_active
-            self._tokens_since_adapt += n_active
-            self.depth_weighted_tokens += 1.0 * n_active
-            self._depth_since_adapt += 1.0 * n_active
-            rep.n_active = n_active
-            for slot in np.nonzero(mask)[0]:
-                r = self.slot_req[slot]
-                self.steps_taken[slot] += 1
-                self.positions[slot] += 1
-                if self.steps_taken[slot] >= r.max_new:
-                    self._finish(slot)  # trailing sample discarded, like
-                    part[slot] = False  # the synchronous step(); the slot
-                    continue            # leaves the chain PERMANENTLY (a
-                tok = int(ring[slot, j])    # re-admission must not rejoin)
-                r.out_tokens.append(tok)
-                self.current_tok[slot] = tok
-                if r.eos_id is not None and tok == r.eos_id:
-                    self._finish(slot)
-                    part[slot] = False
-            self._step_idx += 1
-            replayed += 1
-        if replayed:
-            self._last_segments_run = len(self._segments)
-            self._last_depth_frac = 1.0
-            rep.decode_stepped = True
-            rep.decode_steps += replayed
-            rep.decode_segments_run = self._last_segments_run
-            rep.decode_depth_frac = self._last_depth_frac
-        if not (self.active & part).any():
-            # chain died: any queued successor window is all-dead compute
-            # (its act masks are false from step 0 — no counter updates,
-            # no page writes) — abandon it without a readback
-            self._win_q.clear()
-            self._carry_valid = False
-        self._maybe_flush(steps=max(1, replayed))
+        with _span("commit"):
+            R = self.cfg.readback_interval
+            replayed = 0
+            for j in range(R):
+                mask = self.active & part
+                if not mask.any():
+                    break
+                n_active = int(mask.sum())
+                self.tokens_served += n_active
+                self._tokens_since_adapt += n_active
+                self.depth_weighted_tokens += 1.0 * n_active
+                self._depth_since_adapt += 1.0 * n_active
+                rep.n_active = n_active
+                for slot in np.nonzero(mask)[0]:
+                    r = self.slot_req[slot]
+                    self.steps_taken[slot] += 1
+                    self.positions[slot] += 1
+                    if self.steps_taken[slot] >= r.max_new:
+                        self._finish(slot)  # trailing sample discarded, like
+                        part[slot] = False  # the synchronous step(); the slot
+                        continue            # leaves the chain PERMANENTLY (a
+                    tok = int(ring[slot, j])    # re-admission must not rejoin)
+                    r.out_tokens.append(tok)
+                    self.current_tok[slot] = tok
+                    if r.eos_id is not None and tok == r.eos_id:
+                        self._finish(slot)
+                        part[slot] = False
+                self._step_idx += 1
+                replayed += 1
+            if replayed:
+                self._last_segments_run = len(self._segments)
+                self._last_depth_frac = 1.0
+                rep.decode_stepped = True
+                rep.decode_steps += replayed
+                rep.decode_segments_run = self._last_segments_run
+                rep.decode_depth_frac = self._last_depth_frac
+            if not (self.active & part).any():
+                # chain died: any queued successor window is all-dead compute
+                # (its act masks are false from step 0 — no counter updates,
+                # no page writes) — abandon it without a readback
+                self._win_q.clear()
+                self._carry_valid = False
+            self._maybe_flush(steps=max(1, replayed))
 
     def sync(self) -> List[Request]:
         """Drain the async pipeline: read back and commit every in-flight
@@ -1508,7 +1535,8 @@ class ContinuousBatchScheduler:
             if not (self.active & part).any():
                 continue                # dead chain: no readback needed
             rep = StepReport()
-            self._commit_window(np.asarray(jax.device_get(ring)), part, rep)
+            self._commit_window(np.asarray(self._sync("ring", ring)), part,
+                                rep)
         self._carry_valid = False
         return self.completed[n0:]
     def ensure_spec(self, k: int):
@@ -1559,7 +1587,7 @@ class ContinuousBatchScheduler:
         if self.page_alloc is not None:
             args = args + (self._tbl_dev(),)
         self.cache, drafts = self._propose(*args)
-        return np.asarray(jax.device_get(drafts))
+        return np.asarray(self._sync("draft", drafts))
 
     def spec_verify(self, drafts: np.ndarray,
                     win_len: np.ndarray) -> np.ndarray:
@@ -1588,8 +1616,7 @@ class ContinuousBatchScheduler:
         if self.page_alloc is not None:
             args = args + (self._tbl_dev(),)
         self.cache, gs, nv = self._verify(*args)
-        gs = np.asarray(jax.device_get(gs))
-        nv = np.asarray(jax.device_get(nv))
+        gs, nv = self._sync("verify", (gs, nv))
         committed = np.zeros(b, np.int64)
         for slot in np.nonzero(run)[0]:
             r = self.slot_req[slot]
@@ -1858,8 +1885,7 @@ class ContinuousBatchScheduler:
                 # so transfers here are the intended work
                 with jax.transfer_guard("allow"):
                     a, s = kops.compress_rows(a)
-            ah = np.asarray(jax.device_get(a))
-            sh = None if s is None else np.asarray(jax.device_get(s))
+            ah, sh = self._sync("export", (a, s))
             if ax >= 0:
                 cut = [slice(None)] * ah.ndim
                 if paged:
@@ -2086,7 +2112,7 @@ class ContinuousBatchScheduler:
         """Sync the cumulative device-side exit histogram to host (an
         intended d2h round-trip, made explicit for the transfer guard) and
         fold in the host-side histogram of verify-committed tokens."""
-        self.exit_counts = np.asarray(jax.device_get(self._counters),
+        self.exit_counts = np.asarray(self._sync("flush", self._counters),
                                       np.int64) + self._host_exit_extra
         return self.exit_counts
 

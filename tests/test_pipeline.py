@@ -205,7 +205,8 @@ def test_sync_budget_one_readback_per_window():
 def test_syn_rules_fire_on_seeded_violations():
     """The analyzer's poll-hot-loop pass: implicit concretization, raw
     numpy conversion, and a dispatch barrier all fire; the legal batched
-    ``np.asarray(jax.device_get(...))`` idiom stays silent."""
+    ``np.asarray(jax.device_get(...))`` idiom and the scheduler's
+    ``self._sync`` helper stay silent."""
     seeded = '''
 import jax
 import numpy as np
@@ -241,6 +242,7 @@ class Pool:
     def poll(self):
         ring = self._decode(self.cache)
         host = np.asarray(jax.device_get(ring))   # batched readback
+        go = bool(self._sync("probe", ring))      # the scheduler's helper
         return int(jax.device_get(ring[0]))       # explicit commit read
 '''
     assert [f.rule for f in lint_source(legal, "legal.py")] == []
