@@ -213,9 +213,48 @@ def _decode_positions(position, batch: int):
     return jnp.broadcast_to(pos, (batch,))
 
 
-def gqa_decode(cfg, params, x, cache_k, cache_v, position, *, window: int = 0):
-    """One-token decode.  x [B,1,D]; caches [B,Smax,Nkv,H]; position []
-    int or [B] int (per-slot positions for continuous batching).
+def layer_view(arena, layer):
+    """Layer ``layer`` of a stacked arena ``[n_layers, ...]``; ``layer``
+    None means the arena is unstacked (shared-attention caches and pools).
+    Read inside the layer scan, the slice fuses into its consumer."""
+    if layer is None:
+        return arena
+    return jax.lax.dynamic_index_in_dim(arena, layer, 0, keepdims=False)
+
+
+def write_rows(arena, layer, rows, cols, val):
+    """Write one token's KV row per batch row in place:
+    ``arena[layer, rows[b], cols[b]] = val[b]``.
+
+    The single KV write of every decode path.  A row index past the end
+    of its axis is dropped by the scatter, which is how a masked row
+    writes nothing: contiguous arenas route it to row ``B``, paged pools
+    to the sentinel page ``n_pages``.  Only the written rows are touched;
+    no arena-sized select or copy is built around the write."""
+    idx = (rows, cols) if layer is None else (layer, rows, cols)
+    return arena.at[idx].set(val.astype(arena.dtype), mode="drop")
+
+
+def _contiguous_write(cache, layer, slot, val, write_mask):
+    """Row ``b`` writes ``val[b]`` at ``slot[b]`` unless ``write_mask[b]``
+    is False (None: every row writes)."""
+    b = val.shape[0]
+    rows = jnp.arange(b)
+    if write_mask is not None:
+        rows = jnp.where(write_mask, rows, b)
+    return write_rows(cache, layer, rows, slot, val)
+
+
+def gqa_decode(cfg, params, x, cache_k, cache_v, position, *, window: int = 0,
+               layer=None, write_mask=None):
+    """One-token decode.  x [B,1,D]; caches [B,Smax,Nkv,H], or stacked
+    [n_layers,B,Smax,Nkv,H] with ``layer`` the traced layer index; position
+    [] int or [B] int (per-slot positions for continuous batching).
+
+    The new K/V rows are written into the caches in place; rows with
+    ``write_mask`` False write nothing (their attention then reads the old
+    row at their slot; callers discard those rows' outputs).  Returns the
+    whole (stacked) caches.
 
     window>0: the cache is a RING BUFFER of size window (sub-linear memory
     for long_500k); slot = position % window and scores use gathered
@@ -223,7 +262,7 @@ def gqa_decode(cfg, params, x, cache_k, cache_v, position, *, window: int = 0):
     """
     hd = cfg.resolved_head_dim
     b = x.shape[0]
-    smax = cache_k.shape[1]
+    smax = cache_k.shape[-3]
     q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dnh->bsnh", x, params["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dnh->bsnh", x, params["wv"].astype(x.dtype))
@@ -231,9 +270,9 @@ def gqa_decode(cfg, params, x, cache_k, cache_v, position, *, window: int = 0):
     q = apply_positional(q, pos_b[:, None], cfg.rope, cfg.rope_theta)
     k = apply_positional(k, pos_b[:, None], cfg.rope, cfg.rope_theta)
     slot = (pos_b % smax) if window else jnp.minimum(pos_b, smax - 1)
-    bidx = jnp.arange(b)
-    cache_k = cache_k.at[bidx, slot].set(k[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[bidx, slot].set(v[:, 0].astype(cache_v.dtype))
+    cache_k = _contiguous_write(cache_k, layer, slot, k[:, 0], write_mask)
+    cache_v = _contiguous_write(cache_v, layer, slot, v[:, 0], write_mask)
+    ck, cv = layer_view(cache_k, layer), layer_view(cache_v, layer)
     # validity of each cache slot, per batch row
     idx = jnp.arange(smax)
     if window:
@@ -243,14 +282,14 @@ def gqa_decode(cfg, params, x, cache_k, cache_v, position, *, window: int = 0):
     else:
         valid = idx[None, :] <= pos_b[:, None]             # [B, Smax]
     nq = q.shape[2]
-    nkv = cache_k.shape[2]
+    nkv = ck.shape[2]
     g = nq // nkv
     qg = q.reshape(b, 1, nkv, g, hd)
     scores = jnp.einsum("bsngh,btnh->bngst", qg.astype(jnp.float32),
-                        cache_k.astype(jnp.float32)) / math.sqrt(hd)
+                        ck.astype(jnp.float32)) / math.sqrt(hd)
     scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bngst,btnh->bsngh", probs, cache_v.astype(jnp.float32))
+    out = jnp.einsum("bngst,btnh->bsngh", probs, cv.astype(jnp.float32))
     out = out.reshape(b, 1, nq, hd).astype(x.dtype)
     y = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(x.dtype))
     return y, (cache_k, cache_v)
@@ -300,30 +339,35 @@ def paged_view(pool, tbl):
     return gathered.reshape(b, pps * psz, *gathered.shape[3:])
 
 
-def paged_write(pool, paged: PagedKV, pos_b, val):
-    """Scatter one token per row into its block-table page.
+def paged_write(pool, paged: PagedKV, pos_b, val, layer=None):
+    """Scatter one token per row into its block-table page of ``pool``
+    ([n_pages, P, ...], or stacked [n_layers, n_pages, P, ...] with
+    ``layer`` the traced layer index).
 
     Rows with write_mask False (and rows whose page is unallocated) are
     routed to the sentinel page id and dropped by the scatter — stale slots
     can never corrupt pages owned by live requests.
     """
-    n_pages, psz = pool.shape[0], pool.shape[1]
+    view = pool.shape if layer is None else pool.shape[1:]
+    n_pages, psz = view[0], view[1]
     smax = paged.tbl.shape[1] * psz
     slot = jnp.minimum(pos_b, smax - 1)
     page = jnp.take_along_axis(paged.tbl, (slot // psz)[:, None], axis=1)[:, 0]
     page = jnp.where(paged.write_mask, page, n_pages)
-    return pool.at[page, slot % psz].set(val.astype(pool.dtype), mode="drop")
+    return write_rows(pool, layer, page, slot % psz, val)
 
 
-def gqa_decode_paged(cfg, params, x, pool_k, pool_v, position, paged: PagedKV):
-    """One-token GQA decode against paged KV pools [n_pages, P, Nkv, H].
+def gqa_decode_paged(cfg, params, x, pool_k, pool_v, position, paged: PagedKV,
+                     layer=None):
+    """One-token GQA decode against paged KV pools [n_pages, P, Nkv, H]
+    (stacked [n_layers, ...] with ``layer`` the traced layer index).
 
     Same math as ``gqa_decode`` on the gathered view — bit-identical for the
     jnp path.  No ring-buffer window support (paged mode asserts window==0
     at scheduler init)."""
     hd = cfg.resolved_head_dim
     b = x.shape[0]
-    psz = pool_k.shape[1]
+    psz = pool_k.shape[-3]
     smax = paged.tbl.shape[1] * psz
     q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dnh->bsnh", x, params["wk"].astype(x.dtype))
@@ -331,16 +375,17 @@ def gqa_decode_paged(cfg, params, x, pool_k, pool_v, position, paged: PagedKV):
     pos_b = _decode_positions(position, b)                 # [B]
     q = apply_positional(q, pos_b[:, None], cfg.rope, cfg.rope_theta)
     k = apply_positional(k, pos_b[:, None], cfg.rope, cfg.rope_theta)
-    pool_k = paged_write(pool_k, paged, pos_b, k[:, 0])
-    pool_v = paged_write(pool_v, paged, pos_b, v[:, 0])
+    pool_k = paged_write(pool_k, paged, pos_b, k[:, 0], layer)
+    pool_v = paged_write(pool_v, paged, pos_b, v[:, 0], layer)
+    pk, pv = layer_view(pool_k, layer), layer_view(pool_v, layer)
     nq = q.shape[2]
-    nkv = pool_k.shape[2]
+    nkv = pk.shape[2]
     if PAGED_ATTN_IMPL == "kernel":
         from repro.kernels import ops as kops
-        out = kops.paged_gqa_attention(q, pool_k, pool_v, paged.tbl, pos_b)
+        out = kops.paged_gqa_attention(q, pk, pv, paged.tbl, pos_b)
     else:
-        cache_k = paged_view(pool_k, paged.tbl)            # [B, smax, Nkv, H]
-        cache_v = paged_view(pool_v, paged.tbl)
+        cache_k = paged_view(pk, paged.tbl)                # [B, smax, Nkv, H]
+        cache_v = paged_view(pv, paged.tbl)
         valid = jnp.arange(smax)[None, :] <= pos_b[:, None]
         g = nq // nkv
         qg = q.reshape(b, 1, nkv, g, hd)
@@ -356,16 +401,18 @@ def gqa_decode_paged(cfg, params, x, pool_k, pool_v, position, paged: PagedKV):
 
 
 def mla_decode_paged(cfg, params, x, pool_ckv, pool_krope, position,
-                     paged: PagedKV):
+                     paged: PagedKV, layer=None):
     """One-token MLA decode against paged latent pools
-    ([n_pages, P, R] / [n_pages, P, Hr]) with matrix absorption."""
+    ([n_pages, P, R] / [n_pages, P, Hr], stacked per layer with ``layer``)
+    with matrix absorption."""
     b = x.shape[0]
-    psz = pool_ckv.shape[1]
+    psz = pool_ckv.shape[-2]
     smax = paged.tbl.shape[1] * psz
     pos_b = _decode_positions(position, b)                 # [B]
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(cfg, params, x, pos_b[:, None])
-    pool_ckv = paged_write(pool_ckv, paged, pos_b, c_kv[:, 0])
-    pool_krope = paged_write(pool_krope, paged, pos_b, k_rope[:, 0])
+    pool_ckv = paged_write(pool_ckv, paged, pos_b, c_kv[:, 0], layer)
+    pool_krope = paged_write(pool_krope, paged, pos_b, k_rope[:, 0], layer)
+    pc, pr = layer_view(pool_ckv, layer), layer_view(pool_krope, layer)
     if PAGED_ATTN_IMPL == "kernel":
         from repro.kernels import ops as kops
         # absorb wk_b outside the kernel (FlashInfer MLA trick): the kernel
@@ -374,13 +421,13 @@ def mla_decode_paged(cfg, params, x, pool_ckv, pool_krope, position,
                            params["wk_b"].astype(q_nope.dtype))
         nope, rph = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         out = kops.paged_mla_attention(
-            q_lat, q_rope, pool_ckv, pool_krope, paged.tbl, pos_b,
+            q_lat, q_rope, pc, pr, paged.tbl, pos_b,
             scale=1.0 / math.sqrt(nope + rph))
         out = jnp.einsum("bsnr,rnv->bsnv", out.astype(q_nope.dtype),
                          params["wv_b"].astype(q_nope.dtype))
     else:
-        cache_ckv = paged_view(pool_ckv, paged.tbl)        # [B, smax, R]
-        cache_krope = paged_view(pool_krope, paged.tbl)
+        cache_ckv = paged_view(pc, paged.tbl)              # [B, smax, R]
+        cache_krope = paged_view(pr, paged.tbl)
         valid = jnp.arange(smax)[None, :] <= pos_b[:, None]
         out = mla_scores_ctx(cfg, params, q_nope, q_rope, cache_ckv,
                              cache_krope, valid[:, None, :])
@@ -443,20 +490,22 @@ def mla_forward(cfg, params, x, positions, *, causal: bool = True, window: int =
     return y, (c_kv, k_rope)
 
 
-def mla_decode(cfg, params, x, cache_ckv, cache_krope, position, *, window: int = 0):
+def mla_decode(cfg, params, x, cache_ckv, cache_krope, position, *,
+               window: int = 0, layer=None, write_mask=None):
     """One-token MLA decode against the latent cache (ring buffer if window).
 
     `position` is a [] scalar or a [B] per-slot vector (continuous batching).
+    Stacked caches, ``layer`` and ``write_mask`` as in ``gqa_decode``.
     """
     b = x.shape[0]
-    smax = cache_ckv.shape[1]
+    smax = cache_ckv.shape[-2]
     pos_b = _decode_positions(position, b)                 # [B]
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(cfg, params, x, pos_b[:, None])
     slot = (pos_b % smax) if window else jnp.minimum(pos_b, smax - 1)
-    bidx = jnp.arange(b)
-    cache_ckv = cache_ckv.at[bidx, slot].set(c_kv[:, 0].astype(cache_ckv.dtype))
-    cache_krope = cache_krope.at[bidx, slot].set(
-        k_rope[:, 0].astype(cache_krope.dtype))
+    cache_ckv = _contiguous_write(cache_ckv, layer, slot, c_kv[:, 0],
+                                  write_mask)
+    cache_krope = _contiguous_write(cache_krope, layer, slot, k_rope[:, 0],
+                                    write_mask)
     idx = jnp.arange(smax)
     if window:
         age = (slot[:, None] - idx[None, :]) % smax
@@ -464,6 +513,8 @@ def mla_decode(cfg, params, x, cache_ckv, cache_krope, position, *, window: int 
     else:
         valid = idx[None, :] <= pos_b[:, None]             # [B, Smax]
     mask = valid[:, None, :]                               # [B, Sq=1, Skv]
-    out = mla_scores_ctx(cfg, params, q_nope, q_rope, cache_ckv, cache_krope, mask)
+    out = mla_scores_ctx(cfg, params, q_nope, q_rope,
+                         layer_view(cache_ckv, layer),
+                         layer_view(cache_krope, layer), mask)
     y = jnp.einsum("bsnv,nvd->bsd", out, params["wo"].astype(x.dtype))
     return y, (cache_ckv, cache_krope)

@@ -392,112 +392,137 @@ def init_layer_cache(cfg, kind: str, batch: int, cache_len: int):
     raise ValueError(kind)
 
 
-def _attn_decode_dispatch(cfg, lp_attn, h, cache, position, window, paged=None):
+def _attn_decode_dispatch(cfg, lp_attn, h, cache, layer, position, window,
+                          paged, write_mask):
     if paged is not None:
-        if cfg.attention == "mla":
-            return attn.mla_decode_paged(cfg, lp_attn, h, cache[0], cache[1],
-                                         position, paged)
-        return attn.gqa_decode_paged(cfg, lp_attn, h, cache[0], cache[1],
-                                     position, paged)
-    if cfg.attention == "mla":
-        y, new = attn.mla_decode(cfg, lp_attn, h, cache[0], cache[1], position,
-                                 window=window)
-    else:
-        y, new = attn.gqa_decode(cfg, lp_attn, h, cache[0], cache[1], position,
-                                 window=window)
-    return y, new
+        fn = attn.mla_decode_paged if cfg.attention == "mla" \
+            else attn.gqa_decode_paged
+        return fn(cfg, lp_attn, h, cache[0], cache[1], position, paged, layer)
+    fn = attn.mla_decode if cfg.attention == "mla" else attn.gqa_decode
+    return fn(cfg, lp_attn, h, cache[0], cache[1], position, window=window,
+              layer=layer, write_mask=write_mask)
 
 
-def decode_layer(cfg, kind: str, lp, x, cache, position, window, ctx,
-                 paged=None):
-    """One-token decode through one layer.  Returns (x, new_cache, aux).
+def _put_state(stacks, layer, new, write_mask):
+    """Write one layer's new recurrent state rows ``[B, ...]`` into their
+    stacks ``[n_layers, B, ...]``; rows with ``write_mask`` False keep
+    their old state (the select covers one layer's small state rows)."""
+    def put(stack, n):
+        if write_mask is not None:
+            old = attn.layer_view(stack, layer)
+            n = jnp.where(write_mask.reshape((-1,) + (1,) * (n.ndim - 1)),
+                          n, old)
+        return jax.lax.dynamic_update_index_in_dim(stack, n, layer, 0)
+    return jax.tree.map(put, stacks, new)
 
-    paged != None (an ``attn.PagedKV``): attention caches are paged pools;
-    state kinds are unaffected (their write gating lives in the caller's
-    merge)."""
+
+def decode_layer(cfg, kind: str, lp, x, caches, layer, position, window, ctx,
+                 paged=None, write_mask=None):
+    """One-token decode through layer ``layer`` of a stacked block.
+    ``caches`` are the block's whole stacked caches ``[n_layers, ...]``;
+    the layer's rows are written into them in place.  Returns
+    (x, caches, aux).
+
+    ``write_mask`` [B] bool (None: every row writes) gates the writes
+    themselves: a masked row's KV write is dropped and its state rows keep
+    their old value.  paged != None (an ``attn.PagedKV``): attention caches
+    are paged pools gated by ``paged.write_mask``, which callers pass as
+    ``write_mask`` too."""
     zero = jnp.float32(0.0)
     if kind in ("dense", "enc"):
         h = apply_norm(cfg.norm, x, lp["ln1"])
-        y, new = _attn_decode_dispatch(cfg, lp["attn"], h, cache, position,
-                                       window, paged)
+        y, new = _attn_decode_dispatch(cfg, lp["attn"], h, caches, layer,
+                                       position, window, paged, write_mask)
         x = x + y
         h = apply_norm(cfg.norm, x, lp["ln2"])
         return x + ffn_mod.ffn_forward(lp["ffn"], h, cfg.act), new, zero
     if kind == "moe":
         h = apply_norm(cfg.norm, x, lp["ln1"])
-        y, new = _attn_decode_dispatch(cfg, lp["attn"], h, cache, position,
-                                       window, paged)
+        y, new = _attn_decode_dispatch(cfg, lp["attn"], h, caches, layer,
+                                       position, window, paged, write_mask)
         x = x + y
         h = apply_norm(cfg.norm, x, lp["ln2"])
         y, aux = ffn_mod.moe_ffn(lp["moe"], h, cfg, ctx)
         return x + y, new, aux
     if kind == "pair":
-        x, new_a, _ = decode_layer(cfg, "dense", lp["a"], x, cache["a"], position,
-                                   window, ctx, paged)
-        x, new_b, aux = decode_layer(cfg, "moe", lp["b"], x, cache["b"], position,
-                                     window, ctx, paged)
+        x, new_a, _ = decode_layer(cfg, "dense", lp["a"], x, caches["a"],
+                                   layer, position, window, ctx, paged,
+                                   write_mask)
+        x, new_b, aux = decode_layer(cfg, "moe", lp["b"], x, caches["b"],
+                                     layer, position, window, ctx, paged,
+                                     write_mask)
         return x, {"a": new_a, "b": new_b}, aux
-    if kind == "mamba":
+    if kind in ("mamba", "mlstm", "slstm"):
+        cache = jax.tree.map(lambda a: attn.layer_view(a, layer), caches)
         h = apply_norm(cfg.norm, x, lp["ln"])
-        y, st, cv = ssm_mod.mamba2_decode(cfg, lp["mamba"], h, cache[0], cache[1])
-        return x + y, (st, cv), zero
-    if kind == "mlstm":
-        h = apply_norm(cfg.norm, x, lp["ln"])
-        y, new = xlstm_mod.mlstm_decode(cfg, lp["mlstm"], h, cache)
-        return x + y, new, zero
-    if kind == "slstm":
-        h = apply_norm(cfg.norm, x, lp["ln"])
-        y, new = xlstm_mod.slstm_decode(cfg, lp["slstm"], h, cache)
-        return x + y, new, zero
+        if kind == "mamba":
+            y, st, cv = ssm_mod.mamba2_decode(cfg, lp["mamba"], h, cache[0],
+                                              cache[1])
+            new = (st, cv)
+        elif kind == "mlstm":
+            y, new = xlstm_mod.mlstm_decode(cfg, lp["mlstm"], h, cache)
+        else:
+            y, new = xlstm_mod.slstm_decode(cfg, lp["slstm"], h, cache)
+        return x + y, _put_state(caches, layer, new, write_mask), zero
     if kind == "decx":
         h = apply_norm(cfg.norm, x, lp["ln1"])
-        y, new_self = attn.gqa_decode(cfg, lp["self_attn"], h, cache["self"][0],
-                                      cache["self"][1], position, window=window)
+        y, new_self = attn.gqa_decode(
+            cfg, lp["self_attn"], h, caches["self"][0], caches["self"][1],
+            position, window=window, layer=layer, write_mask=write_mask)
         x = x + y
         h = apply_norm(cfg.norm, x, lp["ln2"])
-        y = attn.cross_decode(cfg, lp["cross_attn"], h, cache["cross"][0],
-                              cache["cross"][1])
+        cross = jax.tree.map(lambda a: attn.layer_view(a, layer),
+                             caches["cross"])
+        y = attn.cross_decode(cfg, lp["cross_attn"], h, cross[0], cross[1])
         x = x + y
         h = apply_norm(cfg.norm, x, lp["ln3"])
         return (x + ffn_mod.ffn_forward(lp["ffn"], h, cfg.act),
-                {"self": new_self, "cross": cache["cross"]}, zero)
+                {"self": new_self, "cross": caches["cross"]}, zero)
     raise ValueError(kind)
 
 
 def decode_scan_block(cfg, kind: str, bparams, x, caches, position, window, ctx,
-                      paged=None):
-    """Decode through a stacked block, scanning layers with per-layer caches.
+                      paged=None, write_mask=None):
+    """Decode through a stacked block, scanning its layers.
 
-    Paged attention caches are stacked pools ``[n, n_pages, P, ...]`` — the
-    scan unstacks the layer axis exactly like contiguous rows; the block
-    table (inside ``paged``) is shared across layers."""
+    The stacked caches ride in the scan's carry and each layer writes its
+    rows into them in place (``decode_layer``), so no per-layer cache copy
+    and no fresh stacked output is built: with the caches donated, a step
+    touches only the rows it writes.  ``write_mask`` [B] gates those
+    writes themselves (see ``decode_layer``); callers need no merge.
+    Paged attention caches are stacked pools ``[n, n_pages, P, ...]``
+    addressed the same way; the block table (inside ``paged``) is shared
+    across layers."""
     n = jax.tree.leaves(bparams)[0].shape[0]
     if n == 1:
         lp = jax.tree.map(lambda a: a[0], bparams)
-        cc = jax.tree.map(lambda a: a[0], caches)
-        x, new, aux = decode_layer(cfg, kind, lp, x, cc, position, window, ctx,
-                                   paged)
-        return x, jax.tree.map(lambda a: a[None], new), aux
+        return decode_layer(cfg, kind, lp, x, caches, 0, position, window,
+                            ctx, paged, write_mask)
 
     def body(carry, inp):
-        xx = carry
-        lp, cc = inp
-        xx, new, aux = decode_layer(cfg, kind, lp, xx, cc, position, window,
-                                    ctx, paged)
-        return xx, (new, aux)
+        xx, cc = carry
+        lp, i = inp
+        xx, cc, aux = decode_layer(cfg, kind, lp, xx, cc, i, position, window,
+                                   ctx, paged, write_mask)
+        return (xx, cc), aux
 
-    x, (new_caches, auxs) = jax.lax.scan(body, x, (bparams, caches))
-    return x, new_caches, jnp.sum(auxs)
+    (x, caches), auxs = jax.lax.scan(body, (x, caches),
+                                     (bparams, jnp.arange(n)))
+    return x, caches, jnp.sum(auxs)
 
 
-def run_shared_attn_decode(cfg, sp, x, cache, position, window, paged=None):
+def run_shared_attn_decode(cfg, sp, x, cache, position, window, paged=None,
+                           write_mask=None):
+    """The shared attention block over its unstacked cache; writes gated as
+    in ``decode_layer``."""
     h = apply_norm(cfg.norm, x, sp["ln1"])
     if paged is not None:
         y, new = attn.gqa_decode_paged(cfg, sp["attn"], h, cache[0], cache[1],
                                        position, paged)
     else:
         y, new = attn.gqa_decode(cfg, sp["attn"], h, cache[0], cache[1],
-                                 position, window=window)
+                                 position, window=window,
+                                 write_mask=write_mask)
     x = x + y
     h = apply_norm(cfg.norm, x, sp["ln2"])
     return x + ffn_mod.ffn_forward(sp["ffn"], h, cfg.act), new

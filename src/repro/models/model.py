@@ -22,7 +22,8 @@ only the segments each token still needs:
     logits     = m.finalize_decode(params, x)
 
 ``alive`` [B] gates per-slot work: an exited slot's hidden state is frozen
-(passthrough) and its KV/state rows are not written; every slot's token is
+(passthrough) and its KV/state rows are not written (the mask gates the row
+write itself, in place); every slot's token is
 produced by ``finalize_decode`` (final norm + LM head) over its — possibly
 early-frozen — hidden state, CALM-style, so exit heads act purely as
 entropy probes.  With no exits fired the segmented path is bit-identical to
@@ -77,8 +78,8 @@ def _entropy(logits):
 
 
 def _row_where(mask, axis):
-    """Per-leaf row select: take ``new`` where ``mask`` along ``axis``.
-    Shared by cache merging (admissions) and alive-masked segment writes."""
+    """Per-leaf row select: take ``new`` where ``mask`` along ``axis``
+    (cache merging at admission)."""
     def f(new, old):
         shape = [1] * new.ndim
         shape[axis] = -1
@@ -325,52 +326,38 @@ class Model:
                 for _ in range(n_sites)]
         return cache
 
-    def merge_decode_cache(self, take_new, new_cache, old_cache, *,
-                           paged: bool = False):
+    def merge_decode_cache(self, take_new, new_cache, old_cache):
         """Row-wise cache merge: slot b takes `new_cache` where take_new[b].
 
-        Used by the continuous-batching scheduler to admit freshly prefilled
-        requests into freed slots without touching in-flight slots.  Block
-        caches are stacked [n_layers, B, ...] (batch axis 1); shared-attn
-        caches are [B, ...] (batch axis 0).
-
-        paged=True: attention leaves are global pools with NO batch axis —
-        their writes were already gated per-row inside the attention step
-        (sentinel-routed scatter), so the new pool is taken wholesale; only
-        SSM/xLSTM state leaves still merge row-wise.
+        Serves admissions only: the scheduler merges a freshly prefilled
+        contiguous arena into freed slots once per admission, without
+        touching in-flight slots.  Decode and prefill steps never merge:
+        their write mask gates each row write in place (``decode_step``,
+        ``decode_segment``), and paged arenas prefill straight into the
+        pool.  Block caches are stacked [n_layers, B, ...] (batch axis 1);
+        shared-attn caches are [B, ...] (batch axis 0).
         """
-        if not paged:
-            out = {"blocks": [jax.tree.map(_row_where(take_new, 1), n, o)
-                              for n, o in zip(new_cache["blocks"],
-                                              old_cache["blocks"])]}
-            if "shared_attn" in old_cache:
-                out["shared_attn"] = [
-                    jax.tree.map(_row_where(take_new, 0), n, o)
-                    for n, o in zip(new_cache["shared_attn"],
-                                    old_cache["shared_attn"])]
-            return out
-        blocks = []
-        for kind, n, o in zip(self.scan_block_kinds(), new_cache["blocks"],
-                              old_cache["blocks"]):
-            if kind in B.PAGED_KINDS:
-                blocks.append(n)
-            else:
-                blocks.append(jax.tree.map(_row_where(take_new, 1), n, o))
-        out = {"blocks": blocks}
+        out = {"blocks": [jax.tree.map(_row_where(take_new, 1), n, o)
+                          for n, o in zip(new_cache["blocks"],
+                                          old_cache["blocks"])]}
         if "shared_attn" in old_cache:
-            out["shared_attn"] = list(new_cache["shared_attn"])
+            out["shared_attn"] = [
+                jax.tree.map(_row_where(take_new, 0), n, o)
+                for n, o in zip(new_cache["shared_attn"],
+                                old_cache["shared_attn"])]
         return out
 
     def decode_step(self, params, cache, tokens, position, *,
-                    long_mode: bool = False, paged=None):
+                    long_mode: bool = False, paged=None, write_mask=None):
         """tokens [B,1] int32; position [] int32 or [B] int32 (per-slot
         positions — continuous batching serves requests at different depths
         in one fixed-shape step).
 
-        paged != None (an ``attention.PagedKV``): attention caches are paged
-        pools addressed through the bundled block table; KV writes are gated
-        per-row by its write_mask.  State-leaf gating stays with the
-        caller's ``merge_decode_cache(..., paged=True)``.
+        ``write_mask`` [B] bool gates every KV and state row write in place:
+        a masked row writes nothing and its cache rows keep their values
+        (None: every row writes).  paged != None (an
+        ``attention.PagedKV``): attention caches are paged pools addressed
+        through the bundled block table, and its ``write_mask`` is the mask.
 
         Returns (logits [B,V] fp32, exit_entropies [n_exits,B] fp32, cache).
         Exit entropies feed the early-exit policy in serving/engine.py.
@@ -379,6 +366,8 @@ class Model:
         x = embed(tokens, params["embed"])
         window = self._window(long_mode)
         bsz = tokens.shape[0]
+        if paged is not None:
+            write_mask = paged.write_mask
         if cfg.rope == "mrope":
             # text token: all three components equal `position`
             pass  # handled inside attention via scalar positions
@@ -393,14 +382,14 @@ class Model:
                 _, kind, n, _ = step
                 x, nc, a = B.decode_scan_block(
                     cfg, kind, params["blocks"][bi], x, cache["blocks"][bi],
-                    position, window, self.ctx, paged)
+                    position, window, self.ctx, paged, write_mask)
                 new_blocks.append(nc)
                 aux = aux + a
                 bi += 1
             elif step[0] == "shared_attn":
                 x, nkv = B.run_shared_attn_decode(
                     cfg, params["shared_attn"], x, cache["shared_attn"][sa_i],
-                    position, window, paged)
+                    position, window, paged, write_mask)
                 new_sa[sa_i] = nkv
                 sa_i += 1
             elif step[0] == "exit":
@@ -457,14 +446,16 @@ class Model:
 
         ``alive`` [B] bool gates per-slot effects: rows that already exited
         keep their hidden state (passthrough) and their cache rows are not
-        written.  With ``alive`` all-true this is exactly the corresponding
-        slice of the monolithic ``decode_step`` (bit-identical).  Returns
-        ``(x, cache)`` where ``cache`` is the full cache dict with only this
-        segment's entries replaced.
+        written — ``alive`` is the write mask of every KV and state row
+        write, which it gates in place (no select over the cache).  With
+        ``alive`` all-true this is exactly the corresponding slice of the
+        monolithic ``decode_step`` (bit-identical).  Returns ``(x, cache)``
+        where ``cache`` is the full cache dict with only this segment's
+        entries replaced.
 
-        paged != None: attention leaves are pools (pool writes gated inside
-        the step by ``paged.write_mask``; the merged pool is taken
-        wholesale), state leaves merge on ``alive``.  ``passthrough``
+        paged != None: attention leaves are pools whose writes are gated by
+        ``paged.write_mask`` (the scheduler passes ``alive`` there too).
+        ``passthrough``
         optionally decouples the HIDDEN-STATE passthrough mask from the
         cache-write mask: the scheduler passes ``alive = alive & active``
         (so stale slots never write pool pages or state rows) but keeps
@@ -483,25 +474,14 @@ class Model:
         for st in seg.steps:
             if st[0] == "scan":
                 _, kind, bi = st
-                x, nc, _ = B.decode_scan_block(
+                x, new_blocks[bi], _ = B.decode_scan_block(
                     cfg, kind, params["blocks"][bi], x, cache["blocks"][bi],
-                    position, window, self.ctx, paged)
-                if paged is not None and kind in B.PAGED_KINDS:
-                    new_blocks[bi] = nc
-                else:
-                    # blocks are stacked [n_layers, B, ...]: batch axis 1
-                    new_blocks[bi] = jax.tree.map(_row_where(alive, 1), nc,
-                                                  cache["blocks"][bi])
+                    position, window, self.ctx, paged, alive)
             else:
                 _, sa_i = st
-                x, nkv = B.run_shared_attn_decode(
+                x, new_sa[sa_i] = B.run_shared_attn_decode(
                     cfg, params["shared_attn"], x, cache["shared_attn"][sa_i],
-                    position, window, paged)
-                if paged is not None:
-                    new_sa[sa_i] = nkv
-                else:
-                    new_sa[sa_i] = jax.tree.map(_row_where(alive, 0), nkv,
-                                                cache["shared_attn"][sa_i])
+                    position, window, paged, alive)
         x = jnp.where(passthrough[:, None, None], x, x_in)
         out: Dict[str, Any] = {"blocks": new_blocks}
         if cfg.shared_attn_period:

@@ -554,11 +554,9 @@ class ContinuousBatchScheduler:
                     tok = jax.lax.dynamic_slice_in_dim(tokens, i, 1, axis=1)
                     t = t0 + i
                     act = (t < lengths) & (t >= start)
-                    logits, _, new_cache = model.decode_step(
+                    logits, _, cache = model.decode_step(
                         params, cache, tok, t, long_mode=cfg.long_mode,
                         paged=attn_mod.PagedKV(tbl, act))
-                    cache = model.merge_decode_cache(act, new_cache, cache,
-                                                     paged=True)
                     last = jnp.where((t == lengths - 1)[:, None], logits,
                                      last)
                     return (cache, last), None
@@ -570,8 +568,9 @@ class ContinuousBatchScheduler:
             return chunk
 
         def chunk(params, cache, tokens, t0, lengths, last_logits):
-            """Replay ``tokens`` [B,C] at positions t0..t0+C-1; rows update
-            only while t < lengths[b].  Carries the last real token's logits
+            """Replay ``tokens`` [B,C] at positions t0..t0+C-1; rows write
+            their cache rows only while t < lengths[b] (the write mask gates
+            each row write in place).  Carries the last real token's logits
             per row so admission can sample the first output token."""
             n = tokens.shape[1]
 
@@ -579,10 +578,9 @@ class ContinuousBatchScheduler:
                 cache, last = carry
                 tok = jax.lax.dynamic_slice_in_dim(tokens, i, 1, axis=1)
                 t = t0 + i
-                logits, _, new_cache = model.decode_step(
-                    params, cache, tok, t, long_mode=cfg.long_mode)
-                act = t < lengths
-                cache = model.merge_decode_cache(act, new_cache, cache)
+                logits, _, cache = model.decode_step(
+                    params, cache, tok, t, long_mode=cfg.long_mode,
+                    write_mask=t < lengths)
                 last = jnp.where((t == lengths - 1)[:, None], logits, last)
                 return (cache, last), None
 
@@ -616,33 +614,16 @@ class ContinuousBatchScheduler:
         model, cfg = self.model, self.cfg
         n_exits, vocab = self._n_exits, self._vocab
 
-        if cfg.paged:
-            def step(params, cache, tokens, positions, active, counters,
-                     threshold, key, step_idx, tbl):
-                # pool/state writes gate on ``active`` — stale slots must
-                # not touch pages they no longer own (the unpaged step
-                # tolerates their garbage writes because each slot has a
-                # private row; a shared pool does not)
-                logits, ee, new_cache = model.decode_step(
-                    params, cache, tokens, positions,
-                    long_mode=cfg.long_mode,
-                    paged=attn_mod.PagedKV(tbl, active))
-                cache = model.merge_decode_cache(active, new_cache, cache,
-                                                 paged=True)
-                if n_exits:
-                    idx = first_exit_index(ee, threshold, vocab)
-                else:
-                    idx = jnp.zeros((tokens.shape[0],), jnp.int32)
-                greedy, nxt, counters = self._sample_and_count(
-                    logits, idx, active, counters, key, step_idx)
-                return greedy, nxt, cache, counters
-
-            return step
-
         def step(params, cache, tokens, positions, active, counters,
-                 threshold, key, step_idx):
+                 threshold, key, step_idx, *rest):
+            # paged arenas pass their block table last, and their pool and
+            # state writes gate on ``active``: stale slots must not touch
+            # pages they no longer own (a contiguous arena tolerates their
+            # garbage writes because each slot has a private row)
+            paged = attn_mod.PagedKV(rest[0], active) if rest else None
             logits, ee, cache = model.decode_step(
-                params, cache, tokens, positions, long_mode=cfg.long_mode)
+                params, cache, tokens, positions, long_mode=cfg.long_mode,
+                paged=paged)
             if n_exits:
                 idx = first_exit_index(ee, threshold, vocab)
             else:
@@ -683,15 +664,9 @@ class ContinuousBatchScheduler:
                 cache, cur, pos, alive, budget, counters = carry
                 act = alive
                 tok_in = cur[:, None]
-                if paged:
-                    logits, ee, new_cache = model.decode_step(
-                        params, cache, tok_in, pos, long_mode=cfg.long_mode,
-                        paged=attn_mod.PagedKV(tbl, act))
-                    cache = model.merge_decode_cache(act, new_cache, cache,
-                                                     paged=True)
-                else:
-                    logits, ee, cache = model.decode_step(
-                        params, cache, tok_in, pos, long_mode=cfg.long_mode)
+                logits, ee, cache = model.decode_step(
+                    params, cache, tok_in, pos, long_mode=cfg.long_mode,
+                    paged=attn_mod.PagedKV(tbl, act) if paged else None)
                 if n_exits:
                     idx = first_exit_index(ee, threshold, vocab)
                 else:
@@ -792,42 +767,30 @@ class ContinuousBatchScheduler:
     # the state after the scan equals sequential decode of exactly the
     # accepted tokens.
     # ------------------------------------------------------------------
+    def _gated_decode(self, params, cache, tokens, pos, act, tbl):
+        """``decode_step`` whose KV and state writes land only in rows
+        ``act``; paged arenas (``tbl`` not None) gate through the block
+        table's sentinel page."""
+        return self.model.decode_step(
+            params, cache, tokens, pos, long_mode=self.cfg.long_mode,
+            paged=None if tbl is None else attn_mod.PagedKV(tbl, act),
+            write_mask=act)
+
     def _make_propose(self, k: int):
         """Draft-side proposer: ``k`` write-gated greedy decode steps in one
         jitted scan.  Step j feeds the running token at ``pos0 + j`` while
         ``active & (j < win_len)`` and emits the next greedy draft.  The
         k-th dispatch feeds the last draft so its KV row is written — on a
-        full accept the resynced draft would otherwise attend to a hole."""
-        model, cfg = self.model, self.cfg
-        if cfg.paged:
-            def propose(params, cache, tok0, pos0, active, win_len, tbl):
-                def body(carry, j):
-                    cache, cur = carry
-                    act = active & (j < win_len)
-                    logits, _, new_cache = model.decode_step(
-                        params, cache, cur[:, None], pos0 + j,
-                        long_mode=cfg.long_mode,
-                        paged=attn_mod.PagedKV(tbl, act))
-                    cache = model.merge_decode_cache(act, new_cache, cache,
-                                                     paged=True)
-                    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    cur = jnp.where(act, greedy, cur)
-                    return (cache, cur), greedy
+        full accept the resynced draft would otherwise attend to a hole.
+        Paged arenas pass their block table as the trailing argument."""
+        def propose(params, cache, tok0, pos0, active, win_len, *rest):
+            tbl = rest[0] if rest else None
 
-                (cache, _), drafts = jax.lax.scan(body, (cache, tok0),
-                                                  jnp.arange(k))
-                return cache, drafts.T
-
-            return propose
-
-        def propose(params, cache, tok0, pos0, active, win_len):
             def body(carry, j):
                 cache, cur = carry
                 act = active & (j < win_len)
-                logits, _, new_cache = model.decode_step(
-                    params, cache, cur[:, None], pos0 + j,
-                    long_mode=cfg.long_mode)
-                cache = model.merge_decode_cache(act, new_cache, cache)
+                logits, _, cache = self._gated_decode(
+                    params, cache, cur[:, None], pos0 + j, act, tbl)
                 greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 cur = jnp.where(act, greedy, cur)
                 return (cache, cur), greedy
@@ -845,39 +808,17 @@ class ContinuousBatchScheduler:
         on device.  Step i runs while every earlier draft matched
         (``ok``) — so ``acts`` is a per-slot contiguous prefix whose length
         is the committed count: the accepted drafts plus one corrected (or
-        bonus) target token.  Rejected positions never write the cache."""
-        model, cfg = self.model, self.cfg
-        if cfg.paged:
-            def verify(params, cache, tokens, pos0, active, win_len, tbl):
-                def body(carry, i):
-                    cache, ok = carry
-                    tok = jax.lax.dynamic_slice_in_dim(tokens, i, 1, axis=1)
-                    act = active & ok & (i < win_len)
-                    logits, _, new_cache = model.decode_step(
-                        params, cache, tok, pos0 + i, long_mode=cfg.long_mode,
-                        paged=attn_mod.PagedKV(tbl, act))
-                    cache = model.merge_decode_cache(act, new_cache, cache,
-                                                     paged=True)
-                    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    nxt = jax.lax.dynamic_slice_in_dim(
-                        tokens, jnp.minimum(i + 1, k - 1), 1, axis=1)[:, 0]
-                    ok = ok & (greedy == nxt)
-                    return (cache, ok), (greedy, act)
+        bonus) target token.  Rejected positions never write the cache.
+        Paged arenas pass their block table as the trailing argument."""
+        def verify(params, cache, tokens, pos0, active, win_len, *rest):
+            tbl = rest[0] if rest else None
 
-                (cache, _), (gs, acts) = jax.lax.scan(
-                    body, (cache, jnp.ones_like(active)), jnp.arange(k))
-                return cache, gs.T, jnp.sum(acts, axis=0).astype(jnp.int32)
-
-            return verify
-
-        def verify(params, cache, tokens, pos0, active, win_len):
             def body(carry, i):
                 cache, ok = carry
                 tok = jax.lax.dynamic_slice_in_dim(tokens, i, 1, axis=1)
                 act = active & ok & (i < win_len)
-                logits, _, new_cache = model.decode_step(
-                    params, cache, tok, pos0 + i, long_mode=cfg.long_mode)
-                cache = model.merge_decode_cache(act, new_cache, cache)
+                logits, _, cache = self._gated_decode(
+                    params, cache, tok, pos0 + i, act, tbl)
                 greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 nxt = jax.lax.dynamic_slice_in_dim(
                     tokens, jnp.minimum(i + 1, k - 1), 1, axis=1)[:, 0]

@@ -18,6 +18,9 @@ Three layers of guarantees:
   depth-weighted step cost matches the exit histogram; the tiered cluster's
   virtual clocks charge the truncated cost, so device/edge p50 drops.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,6 +98,143 @@ def test_exited_rows_freeze_hidden_and_cache(arch):
         assert (np.asarray(got)[:, 1] == np.asarray(init)[:, 1]).all()
     # row 0 alive: identical to the all-alive run
     assert (np.asarray(x_masked)[0] == np.asarray(x_full)[0]).all()
+
+
+# ---------------------------------------------------------------------------
+# gated in-place row writes: the write mask gates each KV / state row write,
+# and the result equals the select formula ``where(mask, new, old)`` that
+# used to run over whole stacked cache leaves
+# ---------------------------------------------------------------------------
+
+# one GQA, one MLA (+MoE) and one shared-attention + SSM config; at 4 layers
+# the GQA and MLA configs' last segment scans a stack of 3 layers
+WRITE_ARCHS = ("starcoder2-3b-smoke", "deepseek-v3-671b-smoke",
+               "zamba2-1.2b-smoke")
+
+
+def _deep_model(arch, layers=4):
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers)
+    m = Model(cfg)
+    return cfg, m, m.init(jax.random.PRNGKey(0))
+
+
+def _filled_cache(m, b, clen, seed):
+    """A contiguous decode cache of random values, so a stray write shows."""
+    leaves, tree = jax.tree.flatten(m.init_decode_cache(b, clen))
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return tree.unflatten([
+        jax.random.normal(k, a.shape, jnp.float32).astype(a.dtype)
+        for k, a in zip(keys, leaves)])
+
+
+def _select_rows(mask, new, old):
+    """Row select over whole leaves: stacked blocks [layers, B, ...] on axis
+    1, shared-attention caches [B, ...] on axis 0."""
+    def sel(axis):
+        def f(n, o):
+            shape = [1] * n.ndim
+            shape[axis] = -1
+            return jnp.where(mask.reshape(shape), n, o)
+        return f
+    out = {"blocks": [jax.tree.map(sel(1), n, o)
+                      for n, o in zip(new["blocks"], old["blocks"])]}
+    if "shared_attn" in old:
+        out["shared_attn"] = [jax.tree.map(sel(0), n, o)
+                              for n, o in zip(new["shared_attn"],
+                                              old["shared_attn"])]
+    return out
+
+
+def _assert_trees_equal(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+@pytest.mark.parametrize("arch", WRITE_ARCHS)
+def test_segment_gated_writes_match_select_formula(arch):
+    """Every segment stage with a mixed ``alive`` mask: masked rows' cache
+    stays bitwise unchanged, alive rows' cache and hidden equal the
+    all-alive run, i.e. the cache is ``where(alive, new, old)``."""
+    cfg, m, params = _deep_model(arch)
+    b = 4
+    cache = _filled_cache(m, b, 16, seed=1)
+    rs = np.random.RandomState(4)
+    toks = jnp.asarray(rs.randint(0, cfg.vocab_size, (b, 1)), jnp.int32)
+    pos = jnp.asarray([2, 5, 3, 7], jnp.int32)
+    alive = jnp.asarray([True, False, True, False])
+    x = m.embed_decode_tokens(params, toks)
+    for seg in m.decode_segments:
+        stage = jax.jit(lambda c, x, al, seg=seg: m.decode_segment(
+            params, c, x, seg, pos, al))
+        x_full, c_full = stage(cache, x, jnp.ones((b,), bool))
+        x_mask, c_mask = stage(cache, x, alive)
+        _assert_trees_equal(c_mask, _select_rows(alive, c_full, cache))
+        want_x = jnp.where(alive[:, None, None], x_full, x)
+        assert (np.asarray(x_mask) == np.asarray(want_x)).all()
+        x = x_full
+
+
+@pytest.mark.parametrize("arch", WRITE_ARCHS)
+def test_prefill_chunk_gated_writes_match_replay_merge(arch):
+    """The unpaged prefill chunk over ragged lengths gives bitwise the same
+    cache and last logits as replaying ``decode_step`` ungated and merging
+    each step's cache with ``where(t < lengths, new, old)``.  Eager, like
+    the composition test above: two programs may fuse differently."""
+    cfg, m, params = _deep_model(arch)
+    b, chunk_len = 4, 8
+    sched = ContinuousBatchScheduler(m, params, SchedulerConfig(
+        n_slots=b, max_len=24, prefill_chunk=chunk_len))
+    chunk = sched._make_prefill_chunk()
+
+    def replay_merge(cache, tokens, t0, lengths, last):
+        for i in range(tokens.shape[1]):
+            t = t0 + i
+            logits, _, new = m.decode_step(params, cache, tokens[:, i:i + 1],
+                                           t)
+            cache = _select_rows(t < lengths, new, cache)
+            last = jnp.where((t == lengths - 1)[:, None], logits, last)
+        return cache, last
+
+    rs = np.random.RandomState(5)
+    lengths = jnp.asarray([13, 3, 8, 16], jnp.int32)
+    tokens = jnp.asarray(rs.randint(0, cfg.vocab_size, (b, 2 * chunk_len)),
+                         jnp.int32)
+    got = want = (_filled_cache(m, b, 24, seed=2),
+                  jnp.zeros((b, cfg.vocab_size), jnp.float32))
+    with jax.disable_jit():
+        for ci in range(2):
+            toks = tokens[:, ci * chunk_len:(ci + 1) * chunk_len]
+            t0 = jnp.int32(ci * chunk_len)
+            got = chunk(params, got[0], toks, t0, lengths, got[1])
+            want = replay_merge(want[0], toks, t0, lengths, want[1])
+            _assert_trees_equal(got, want)
+
+
+_ARENA_OP = re.compile(r"= \w+\[([\d,]*)\]\S* (select|broadcast)\(")
+
+
+@pytest.mark.parametrize("arch", ("starcoder2-3b-smoke", "zamba2-1.2b-smoke"))
+def test_decode_stages_build_no_arena_sized_select(arch):
+    """Structural guard: the compiled segment stages and unpaged prefill
+    chunk hold no select and no broadcast shaped like a whole cache leaf
+    (the row write is gated in place, not merged over the arena)."""
+    cfg, m, params = _deep_model(arch)
+    sched = ContinuousBatchScheduler(m, params, SchedulerConfig(
+        n_slots=4, max_len=24, prefill_chunk=8))
+    cache_s = jax.eval_shape(sched._init_cache)
+    leaf_shapes = {tuple(a.shape) for a in jax.tree.leaves(cache_s)
+                   if a.ndim >= 4}
+    stages = sched.audit_stages()
+    names = [n for n in stages if n.startswith("segment")] + ["prefill"]
+    for name in names:
+        st = stages[name]
+        text = st.fn.lower(*st.args).compile().as_text()
+        hits = [ln.strip()[:120] for ln in text.splitlines()
+                for mm in [_ARENA_OP.search(ln)]
+                if mm and tuple(int(d) for d in mm.group(1).split(",") if d)
+                in leaf_shapes]
+        assert not hits, f"{name}: arena-sized ops {hits}"
 
 
 # ---------------------------------------------------------------------------
