@@ -41,13 +41,14 @@ def readings(b, log, spec, seed, controls):
     import check
 
     m, params, cell = b["model"], b["sched"].params, spec["cell"]
+    reference = check.load_reference(spec["config"])
     sel = check.select(m, log, spec["traffic"], seed, b["max_len"])
     if not sel["ids"]:
         return {"seed": seed, "requests": 0}
     stages = b["sched"].audit_stages()
     probes = [stages[f"probe{e}"] for e in range(len(m["exit_layers"]))]
     t = time.perf_counter()
-    prog, rp = check.program_numbers(m, params, sel, seed, probes)
+    prog, rp = check.program_numbers(reference, m, params, sel, seed, probes)
     out = {"seed": seed, "requests": len(sel["ids"]),
            "tokens": int(sel["mask"].sum()),
            "check_s": time.perf_counter() - t, "program": prog,
@@ -55,7 +56,7 @@ def readings(b, log, spec, seed, controls):
                dict(prog, wrong_lengths=sel["wrong_lengths"]),
                cell["limits"]))}
     for q in controls:
-        nums = check.control_numbers(m, params, sel, rp, q)
+        nums = check.control_numbers(reference, m, params, sel, rp, q)
         out[q] = nums
         out[q + "_correct"] = check.correct(check.judge(
             dict(nums, wrong_lengths=sel["wrong_lengths"]), cell["limits"]))
@@ -87,7 +88,8 @@ def main(argv=None) -> int:
     for n, seed in enumerate(seeds):
         if n:       # one seed's weights on the chip at a time
             b["sched"].params = params = None
-            params = weights.make(b["shapes"], seed)
+            params = weights.make(b["shapes"], seed,
+                                  spec["config"].get("weight_rules"))
         harness.reset(b["sched"], params)
         reqs = gen.generate(spec["traffic"], spec["cell"], seed, vocab,
                             a.seconds)

@@ -3,8 +3,9 @@ run's ``correct``.
 
 Once the window has closed and the program's state is freed, a sample of
 the requests it served, drawn from the seed and always holding the request
-with the most served tokens, goes through the plain float32 reference
-(``reference/dense.py``) with its prompt and the tokens the window served.
+with the most served tokens, goes through the plain float32 reference that
+the configuration file names (``bench/reference/<name>.py``,
+``load_reference``) with its prompt and the tokens the window served.
 At every served position the reference's best logit and its logit of the
 served token are read.  Greedy decoding serves the argmax, so a right
 program misses the reference's best only by rounding, while a wrong cache
@@ -32,15 +33,28 @@ inside the vocabulary (random weights never emit an end token).
 """
 from __future__ import annotations
 
+import importlib
+import os
 from typing import Dict, List, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
-from reference.dense import Reference
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 EXIT_ROWS = 64          # rows of a probe call that are bisected
 BISECT_STEPS = 24       # normalized entropy to 2 / 2**24
+
+
+def load_reference(config: Dict):
+    """The module ``bench/reference/<name>.py`` that the configuration
+    file names under ``"reference"`` (``dense`` where it names none)."""
+    name = config.get("reference", "dense")
+    if "/" in name or not os.path.isfile(
+            os.path.join(HERE, "reference", name + ".py")):
+        raise SystemExit(f"bench: no reference module "
+                         f"bench/reference/{name}.py")
+    return importlib.import_module("reference." + name)
 
 
 def sample(log, seed: int, k: int) -> List[int]:
@@ -88,7 +102,7 @@ def exit_rows(mask, seed: int, n: int) -> Tuple[np.ndarray, np.ndarray]:
     return rs[pick], ts[pick]
 
 
-def reference_pass(ref: Reference, model: Dict, tokens, look, mask,
+def reference_pass(ref, model: Dict, tokens, look, mask,
                    seed: int, n_rows: int) -> Dict:
     """The reference's side of every number: its best logit and its logit
     of the served token at every position, its final residual stream,
@@ -172,12 +186,14 @@ def select(model: Dict, log, traffic: Dict, seed: int, t: int) -> Dict:
             "wrong_lengths": wrong}
 
 
-def program_numbers(model: Dict, params, sel: Dict, seed: int,
+def program_numbers(reference, model: Dict, params, sel: Dict, seed: int,
                     probes: List) -> Tuple[Dict, Dict]:
-    """The program's numbers, and the reference pass behind them."""
+    """The program's numbers, and the reference pass behind them;
+    ``reference`` is the module of ``load_reference``."""
     n_rows = min([EXIT_ROWS] + [p.args[1].shape[0] for p in probes])
-    rp = reference_pass(Reference(model, params), model, sel["tokens"],
-                        sel["look"], sel["mask"], seed, n_rows)
+    rp = reference_pass(reference.Reference(model, params), model,
+                        sel["tokens"], sel["look"], sel["mask"], seed,
+                        n_rows)
     nums = logit_numbers(rp["best"], rp["at"], sel["mask"])
     nums["exit_entropy_gap"] = entropy_gap(
         [probe_entropy(p, params, xe, model["vocab_size"])
@@ -185,15 +201,15 @@ def program_numbers(model: Dict, params, sel: Dict, seed: int,
     return nums, rp
 
 
-def control_numbers(model: Dict, params, sel: Dict, rp: Dict,
+def control_numbers(reference, model: Dict, params, sel: Dict, rp: Dict,
                     quant: str) -> Dict:
     """The same numbers for the reference computed in a lower precision
     and put in the program's place: at the same prompts and served
     tokens, the token it puts first at each position, and its exit-head
     entropies on the same rows."""
-    rq = Reference(model, params, quant)
+    rq = reference.Reference(model, params, quant)
     _, _, arg = rq.head(rq.hidden(sel["tokens"]), sel["look"])
-    _, at, _ = Reference(model, params).head(rp["x"], arg)
+    _, at, _ = reference.Reference(model, params).head(rp["x"], arg)
     nums = logit_numbers(rp["best"], at, sel["mask"])
     nums["exit_entropy_gap"] = entropy_gap(
         [rq.exit_entropy(xe, e) for e, xe in enumerate(rp["exits"])],
@@ -201,10 +217,11 @@ def control_numbers(model: Dict, params, sel: Dict, rp: Dict,
     return nums
 
 
-def compare(model: Dict, params, log, traffic: Dict, cell: Dict,
-            seed: int, t: int, probes: List):
+def compare(reference, model: Dict, params, log, traffic: Dict,
+            cell: Dict, seed: int, t: int, probes: List):
     """The checks of one run, {name: {value, limit}}, and how many
-    requests failed them.  ``probes`` are the probe stages of
+    requests failed them.  ``reference`` is the module of
+    ``load_reference``; ``probes`` are the probe stages of
     ``audit_stages()``, one per exit head in order."""
     sel = select(model, log, traffic, seed, t)
     ids, mask, limits = sel["ids"], sel["mask"], cell["limits"]
@@ -213,7 +230,8 @@ def compare(model: Dict, params, log, traffic: Dict, cell: Dict,
             "exit_entropy_gap": 0.0}
     failed = 0
     if ids:     # else no_tokens_compared fails
-        nums, rp = program_numbers(model, params, sel, seed, probes)
+        nums, rp = program_numbers(reference, model, params, sel, seed,
+                                   probes)
         g = np.where(mask, rp["best"] - rp["at"], 0.0)[:len(ids)]
         n = np.maximum(mask[:len(ids)].sum(1), 1)
         failed = int(np.sum((g.max(1) > limits["logit_gap"])

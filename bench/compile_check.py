@@ -25,7 +25,6 @@ STAGES = ("init_cache", "fresh_last", "merge", "prefill", "segment",
 
 def check(workload: str, one_chip) -> None:
     import jax
-    from repro.configs import get_config
     from repro.models import Model, ShardCtx
     from repro.serving.scheduler import (ContinuousBatchScheduler,
                                          SchedulerConfig)
@@ -34,7 +33,7 @@ def check(workload: str, one_chip) -> None:
 
     spec = harness.load_spec(workload)
     c, tr, cell = spec["config"], spec["traffic"], spec["cell"]
-    model = Model(get_config(c["preset"]), ShardCtx(None))
+    model = Model(harness.model_config(c), ShardCtx(None))
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     max_len = open_loop.max_len(tr["prompt_len"]) \
         + open_loop.max_len(tr["output_len"])

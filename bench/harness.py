@@ -3,9 +3,14 @@
 Everything a cell is made of is found by name, so a new cell, traffic mix
 or metric is new files plus ``BENCHMARK.json`` entries:
 
-* ``bench/configs/<config>.json``: the repo preset that runs, the model as
-  run (in the source's key names, read by the reference) and the
-  ``SchedulerConfig`` fields it sets;
+* ``bench/configs/<config>.json``: the repo preset that runs and its
+  ``preset_changes`` (a dict for a sub-config such as ``moe`` changes
+  fields of that one), the model as run (in the source's key names, read
+  by the reference), the ``SchedulerConfig`` fields it sets, and where the
+  model is not a dense decoder: ``reference`` (the module
+  ``bench/reference/<name>.py``, ``dense`` by default), ``weight_rules``
+  for its leaves (``weights.py``) and ``shape_keys``, the further source
+  keys that ``describe`` checks;
 * ``bench/traffic/<mix>.json``: the generator (``bench/gen/<name>.py``)
   and its parameters;
 * ``bench/cells/<workload>.json``: the load this cell offers (slots, rate)
@@ -86,25 +91,39 @@ def use_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def describe(cfg) -> Dict:
+def describe(cfg, shape_keys: Optional[Dict] = None) -> Dict:
     """The shape keys of a repo ModelConfig, in the config file's names:
-    the file must describe the model that runs."""
-    return {"num_hidden_layers": cfg.num_layers,
-            "hidden_size": cfg.d_model,
-            "intermediate_size": cfg.d_ff,
-            "num_attention_heads": cfg.num_heads,
-            "num_key_value_heads": cfg.num_kv_heads,
-            "head_dim": cfg.resolved_head_dim,
-            "vocab_size": cfg.vocab_size,
-            "hidden_act": {"silu": "silu",
-                           "gelu": "gelu_pytorch_tanh"}[cfg.act],
-            "norm": cfg.norm,
-            "rope_theta": cfg.rope_theta,
-            "sliding_window": cfg.sliding_window,
-            "tie_word_embeddings": cfg.tie_embeddings,
-            "exit_layers": list(cfg.exits.exit_layers),
-            "exit_entropy_threshold": cfg.exits.entropy_threshold,
-            "dtype": cfg.dtype}
+    the file must describe the model that runs.  ``shape_keys`` adds
+    source keys read from dotted ModelConfig attributes
+    (``{"n_routed_experts": "moe.num_experts"}``) to the common ones."""
+    out = {"num_hidden_layers": cfg.num_layers,
+           "hidden_size": cfg.d_model,
+           "intermediate_size": cfg.d_ff,
+           "num_attention_heads": cfg.num_heads,
+           "num_key_value_heads": cfg.num_kv_heads,
+           "head_dim": cfg.resolved_head_dim,
+           "vocab_size": cfg.vocab_size,
+           "hidden_act": {"silu": "silu",
+                          "gelu": "gelu_pytorch_tanh"}[cfg.act],
+           "norm": cfg.norm,
+           "rope_theta": cfg.rope_theta,
+           "sliding_window": cfg.sliding_window,
+           "tie_word_embeddings": cfg.tie_embeddings,
+           "exit_layers": list(cfg.exits.exit_layers),
+           "exit_entropy_threshold": cfg.exits.entropy_threshold,
+           "dtype": cfg.dtype}
+    for key, attr in (shape_keys or {}).items():
+        if key in out:
+            raise SystemExit(f"bench: shape key {key!r} is a common key; "
+                             "it is always checked as describe() reads it")
+        v = cfg
+        for part in attr.split("."):
+            if not hasattr(v, part):
+                raise SystemExit(f"bench: shape key {key!r} names {attr!r}, "
+                                 "which the ModelConfig has not")
+            v = getattr(v, part)
+        out[key] = list(v) if isinstance(v, tuple) else v
+    return out
 
 
 @dataclasses.dataclass
@@ -117,6 +136,7 @@ class Context:
     n_slots: int
     peaks: Optional[Dict]             # this chip's row of peaks.json
     trace: Optional[Dict] = None      # devtrace.load() of the traced run
+    reference: object = None          # the config's bench/reference module
 
     @property
     def span(self):
@@ -141,6 +161,44 @@ def load_reader(name: str):
     return mod
 
 
+def apply_changes(obj, changes: Dict):
+    """The frozen dataclass ``obj`` with ``changes``: a dict given for a
+    field that holds a dataclass changes fields of that one (``{"moe":
+    {"num_experts": 8}}``), a list for a tuple field becomes a tuple."""
+    out = {}
+    for k, v in changes.items():
+        cur = getattr(obj, k)
+        if isinstance(v, dict) and dataclasses.is_dataclass(cur):
+            v = apply_changes(cur, v)
+        elif isinstance(v, list) and isinstance(cur, tuple):
+            v = tuple(v)
+        out[k] = v
+    return dataclasses.replace(obj, **out)
+
+
+def model_config(c: Dict, smoke: bool = False):
+    """The ModelConfig a configuration file runs: its preset (the
+    ``-smoke`` variant under ``smoke``) with its ``preset_changes``."""
+    from repro.configs import get_config
+    cfg = get_config(c["preset"] + ("-smoke" if smoke else ""))
+    return apply_changes(cfg, c["preset_changes"])
+
+
+def checked_model(c: Dict, name: str, smoke: bool = False):
+    """The ModelConfig that runs and the file's model section, which must
+    describe it; under ``smoke`` the section takes the smoke sizes."""
+    cfg = model_config(c, smoke)
+    want = describe(cfg, c.get("shape_keys"))
+    m = dict(c["model"])
+    if smoke:
+        m.update(want)
+    wrong = {k: (v, m.get(k)) for k, v in want.items() if m.get(k) != v}
+    if wrong:
+        raise SystemExit(f"bench: {name}.json does not describe the preset "
+                         f"that runs: {wrong}")
+    return cfg, m
+
+
 def _peaks(dev) -> Optional[Dict]:
     """The chip's peaks; a TPU missing from the table is an error.  Off
     the TPU (CPU rehearsals only) there are none, so no device metric."""
@@ -158,27 +216,16 @@ def build(spec: Dict, seed: int, seconds: float, *, smoke: bool = False):
     ``smoke`` builds the preset's ``-smoke`` variant with a model section
     derived from it (CPU rehearsals and tests only).  Returns a dict."""
     import jax
-    from repro.configs import get_config
     from repro.models import Model, ShardCtx
     from repro.serving.scheduler import (ContinuousBatchScheduler, Request,
                                          SchedulerConfig)
     import weights
 
     c, traffic, cell = spec["config"], spec["traffic"], spec["cell"]
-    cfg = get_config(c["preset"] + ("-smoke" if smoke else ""))
-    if c["preset_changes"]:
-        cfg = dataclasses.replace(cfg, **c["preset_changes"])
-    m = dict(c["model"])
-    if smoke:
-        m.update(describe(cfg))
-    wrong = {k: (v, m.get(k)) for k, v in describe(cfg).items()
-             if m.get(k) != v}
-    if wrong:
-        raise SystemExit(f"bench: {spec['workload']['config']}.json does "
-                         f"not describe the preset that runs: {wrong}")
+    cfg, m = checked_model(c, spec["workload"]["config"], smoke)
     model = Model(cfg, ShardCtx(None))
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    params = weights.make(shapes, seed)
+    params = weights.make(shapes, seed, c.get("weight_rules"))
     gen = importlib.import_module("gen." + traffic["generator"])
     max_len = gen.max_len(traffic["prompt_len"]) \
         + gen.max_len(traffic["output_len"])
@@ -234,6 +281,7 @@ def run(spec: Dict, seed: int, seconds: float, trace: bool,
         if event == COMPILE_EVENT:
             compiles.append(time.perf_counter())
 
+    reference = check.load_reference(spec["config"])
     jax.monitoring.register_event_duration_secs_listener(on_event)
     try:
         b = build(spec, seed, seconds, smoke=smoke)
@@ -267,13 +315,14 @@ def run(spec: Dict, seed: int, seconds: float, trace: bool,
     sched._pending = None
     del sched, b["sched"], stages
     gc.collect()
-    checks, failed = check.compare(m, b["params"], log, traffic, cell, seed,
-                                   b["max_len"], probes)
+    checks, failed = check.compare(reference, m, b["params"], log,
+                                   traffic, cell, seed, b["max_len"], probes)
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     ctx = Context(log=log, setup_s=setup_s, model=m, cell=cell,
-                  n_slots=cell["n_slots"], peaks=_peaks(dev))
+                  n_slots=cell["n_slots"], peaks=_peaks(dev),
+                  reference=reference)
     out = {}
     if trace:
         ctx.trace = devtrace.load(TRACE_DIR, dev.id)

@@ -34,6 +34,7 @@ SPAN_METRICS = ["admission_ms", "decode_host_ms", "syncs_per_step"]
 def run(spec, seed: int, seconds: float, trace: bool, t_start: float, *,
         smoke: bool = False):
     import jax
+    import check
     import harness
     import spans
 
@@ -50,7 +51,8 @@ def run(spec, seed: int, seconds: float, trace: bool, t_start: float, *,
     dev = jax.devices()[0]
     ctx = harness.Context(log=log, setup_s=setup_s, model=b["model"],
                           cell=spec["cell"], n_slots=spec["cell"]["n_slots"],
-                          peaks=harness._peaks(dev))
+                          peaks=harness._peaks(dev),
+                          reference=check.load_reference(spec["config"]))
     polls = [(p.end - p.start) * 1e3 for p in log.polls
              if p.decode_stepped and not p.prefill_chunks
              and p.start < log.t0 + head]

@@ -27,6 +27,9 @@ float32 copy.  ``quant`` names a lower-precision control, the step a later
 change might be tempted to take: under ``"int8"`` every weight matmul
 takes int8 weights (scaled per output column) and int8 activations (per
 row); under ``"fp8"`` both are float8 e4m3, scaled the same way.
+
+``matmul_params`` and ``attention_flops`` count the model FLOPs of a token
+from the same shapes (``bench/metrics/step_mfu.py``).
 """
 from __future__ import annotations
 
@@ -38,6 +41,25 @@ import jax.numpy as jnp
 import numpy as np
 
 QUANTS = ("none", "int8", "fp8")
+
+
+def matmul_params(m: Dict) -> int:
+    """Matmul parameters one token uses in the full-depth forward:
+    attention and FFN projections of every layer, and the output head (the
+    embedding gather and the exit probes excluded)."""
+    d, f = m["hidden_size"], m["intermediate_size"]
+    nq, nkv, h = (m["num_attention_heads"], m["num_key_value_heads"],
+                  m["head_dim"])
+    ffn = (3 if m["hidden_act"] == "silu" else 2) * d * f
+    layer = 2 * d * nq * h + 2 * d * nkv * h + ffn
+    return m["num_hidden_layers"] * layer + m["vocab_size"] * d
+
+
+def attention_flops(m: Dict, c) -> float:
+    """FLOPs of one token's attention over c positions, every layer: the
+    scores q.k and the weighted sum p.v, 2 * Nq * H * c each."""
+    return 4.0 * m["num_hidden_layers"] * m["num_attention_heads"] \
+        * m["head_dim"] * c
 
 
 def _q(a, axis, quant):

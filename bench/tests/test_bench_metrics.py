@@ -11,6 +11,7 @@ sys.path[:0] = [BENCH]
 
 import harness  # noqa: E402
 from gen.driver import Poll, WindowLog  # noqa: E402
+from reference import dense  # noqa: E402
 
 PEAKS = {"bf16_flops_per_s": 200e12, "hbm_bytes_per_s": 800e9}
 TINY = {"num_hidden_layers": 2, "hidden_size": 4, "intermediate_size": 8,
@@ -36,7 +37,7 @@ def _log():
 
 def _ctx(**kw):
     return harness.Context(log=_log(), setup_s=42.0, model=TINY, cell={},
-                           n_slots=4, peaks=PEAKS, **kw)
+                           n_slots=4, peaks=PEAKS, reference=dense, **kw)
 
 
 def read(name, ctx):
@@ -69,16 +70,17 @@ def test_a_split_metric_finds_its_quantity_s_reader():
 def test_mfu_counts_hand_worked():
     mfu = harness.load_reader("step_mfu.offline")
     # per layer: q,o 2*4*2*2=32, k,v 2*4*1*2=16, ffn 3*4*8=96 -> 144;
-    # two layers and a 10 x 4 head
-    assert mfu.matmul_params(TINY) == 2 * 144 + 40
+    # two layers and a 10 x 4 head (the dense reference counts them)
+    assert dense.matmul_params(TINY) == 2 * 144 + 40
     n2 = 2 * (2 * 144 + 40)
     # a token at context c adds 4 * L * Nq * H * c = 32 c
-    assert mfu.token_flops(TINY, 3) == n2 + 96
+    assert dense.attention_flops(TINY, 3) == 96
+    assert mfu.token_flops(dense, TINY, 3) == n2 + 96
     # prompt of 3 (contexts 1+2+3) then tokens 2 and 3 at contexts 4, 5
-    assert mfu.request_flops(TINY, 3, 1, 3) == 3 * n2 + 32 * 6 \
+    assert mfu.request_flops(dense, TINY, 3, 1, 3) == 3 * n2 + 32 * 6 \
         + 2 * n2 + 32 * 9
     ctx = _ctx()
-    total = mfu.request_flops(TINY, 3, 1, 4)
+    total = mfu.request_flops(dense, TINY, 3, 1, 4)
     assert read("step_mfu.offline", ctx) == pytest.approx(
         100 * total / (10 * PEAKS["bf16_flops_per_s"]))
     ctx.peaks = None

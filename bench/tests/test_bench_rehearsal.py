@@ -23,7 +23,9 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
 
 @pytest.mark.parametrize("workload", WORKLOADS)
 @pytest.mark.parametrize("trace", [False, True])
-def test_short_window_of_every_cell(workload, trace):
+def test_short_window_of_every_cell(workload, trace, tmp_path, monkeypatch):
+    # a trace directory of its own: other test workers trace too
+    monkeypatch.setattr(harness, "TRACE_DIR", str(tmp_path / "trace"))
     spec = harness.load_spec(workload)
     # the CPU replays prompts slowly, the more so beside other test
     # workers: short prompts in short chunks and frequent arrivals, so that

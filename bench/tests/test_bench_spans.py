@@ -216,9 +216,11 @@ def test_traced_async_scheduler_reads_one_ring_per_window(tmp_path,
     assert read("syncs_per_step.offline", _ctx(tr)) is None
 
 
-def test_phases_runs_a_short_window_on_the_cpu():
+def test_phases_runs_a_short_window_on_the_cpu(tmp_path, monkeypatch):
     """``bench/phases.py`` through its own machinery at smoke size: the
     span metrics read, and the gaps take the scheduler's names."""
+    # a trace directory of its own: other test workers trace too
+    monkeypatch.setattr(harness, "TRACE_DIR", str(tmp_path / "trace"))
     spec = harness.load_spec("starcoder2-3b.offline_decode")
     spec["traffic"]["prompt_len"] = {"dist": "uniform", "min": 8, "max": 32}
     spec["traffic"]["trace_seconds"] = 3.0
